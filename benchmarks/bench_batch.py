@@ -19,9 +19,9 @@ num_levels dispatches.
 ``autotune/*`` rows run the kernels/autotune.py block-size sweeps on the
 pack's real ELL plan (the jnp reference form is itself a candidate, so the
 sweep also answers ref-vs-kernel routing), record each kind's winner and
-its winner-vs-default ratio, seed an ``ell_vs_seg`` routing entry from the
-segment_sum/ELL timings above, and persist the tuned table to
-AUTOTUNE_cache.json (CI uploads it as an artifact).
+its winner-vs-default ratio, and seed an ``ell_vs_seg`` routing entry from
+the segment_sum/ELL timings above.  The tuned table lives in the process
+and in BENCH_batch.json's ``autotune`` field; no file is written for it.
 
 ``search/<scheme>/{sequential,batched,speedup}`` rows time compressed
 BM25/TF-IDF top-k ranking (repro/search): one jitted per-corpus scoring
@@ -112,9 +112,7 @@ def _autotune_rows(gb: GrammarBatch, n: int, t_seg: float, t_ell: float,
     compile; the smoke lane only checks the harness executes end to end).
     The ``ell_vs_seg`` routing entry is seeded from the segment_sum/ELL
     timings the traversal section already measured — both engine paths
-    actually timed on this machine — and the whole table persists to the
-    cache file (AUTOTUNE_cache.json unless REPRO_AUTOTUNE_CACHE points
-    elsewhere)."""
+    actually timed on this machine."""
     from repro.kernels import autotune
 
     src, freq, _, num_levels = gb.ell_plan()
@@ -146,8 +144,7 @@ def _autotune_rows(gb: GrammarBatch, n: int, t_seg: float, t_ell: float,
         autotune.shape_bucket(n, gb.R_pad, gb.ell_plan_width()),
         {"use_ref": bool(t_ell > t_seg), "us": min(t_seg, t_ell) * 1e6,
          "default_us": t_seg * 1e6})
-    cache = autotune.save_table()
-    out = {"cache": cache, "kinds": {}}
+    out = {"kinds": {}}
     for kind, e in entries.items():
         ratio = e["default_us"] / max(e["us"], 1e-9)
         emit(f"autotune/{kind}/winner", e["us"] / 1e6, e["winner"])

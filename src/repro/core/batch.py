@@ -77,11 +77,8 @@ from typing import Any, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8 canonical location
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from repro.obs import plan_stage as _plan_stage
 
@@ -466,7 +463,7 @@ def _sharded_program(fn, mesh, in_ndims: Tuple[int, ...],
                  if isinstance(out_ndim, tuple) else spec(out_ndim))
     sm = shard_map(bound, mesh=mesh,
                    in_specs=tuple(spec(nd) for nd in in_ndims),
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     return jax.jit(sm)
 
 
@@ -658,8 +655,9 @@ _leveled_weights_batched_ell = jax.jit(_leveled_ell_impl,
 
 
 def _frontier_fused_impl(ell_src, ell_freq, in_deg, num_levels):
-    """The whole frontier loop in ONE dispatch (kernels.ops dispatches to
-    the fused Pallas kernel on TPU / the jitted fori_loop form on CPU).
+    """The whole frontier loop in ONE dispatch (kernels.ops runs the jitted
+    fori_loop form on TPU and CPU; the fused Pallas kernel is its
+    interpret-mode oracle — kernels/ops.py DESIGN note).
 
     ``num_levels`` — the pack's max DAG depth — is the exact round count
     the while_loop form executes (level-L rules activate in round L+1), so

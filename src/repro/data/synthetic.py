@@ -7,6 +7,11 @@ compression ratio, rule count and DAG depth land in realistic ranges.
 
 ``TABLE2`` mirrors the paper's datasets A–E *scaled down* (CPU container):
 same file-count/size relationships, 1e3–1e5 tokens instead of GBs.
+
+Two optional features shape a corpus like real text at scale: a zipf tail
+that is redrawn instead of clipped onto the last word id (so a large
+vocabulary keeps its long tail), and revision histories, as in a wiki's
+page-history dump, whose nested revisions make the rule DAG deep.
 """
 
 from __future__ import annotations
@@ -27,6 +32,15 @@ class CorpusSpec:
     n_phrases: int
     phrase_len: int
     seed: int = 0
+    # fraction of draws that emit one article's revision history: the
+    # article, then ``revisions - 1`` revisions, each the previous one with
+    # an edit appended.  Each revision nests the one before, so the rule
+    # DAG is about ``revisions`` deep.
+    history_rate: float = 0.0
+    revisions: int = 0
+    # zipf draws beyond the vocabulary are redrawn (True) or clipped onto
+    # the last word id (False)
+    redraw_tail: bool = False
 
 
 # Scaled-down analogues of Table II (A: many small files; B: few big files;
@@ -46,29 +60,56 @@ TABLE2 = {
 
 
 def zipf_words(rng: np.random.Generator, n: int, vocab: int,
-               a: float = 1.3) -> np.ndarray:
-    """Zipf-distributed word ids clipped to the vocab."""
-    w = rng.zipf(a, size=n)
-    return np.minimum(w - 1, vocab - 1).astype(np.int64)
+               a: float = 1.3, redraw_tail: bool = False) -> np.ndarray:
+    """Zipf-distributed word ids, clipped to the vocab or, with
+    ``redraw_tail``, drawn again until they fall inside it."""
+    w = rng.zipf(a, size=n) - 1
+    if not redraw_tail:
+        return np.minimum(w, vocab - 1).astype(np.int64)
+    out = w >= vocab
+    while out.any():
+        w[out] = rng.zipf(a, size=int(out.sum())) - 1
+        out = w >= vocab
+    return w.astype(np.int64)
+
+
+def revision_history(rng: np.random.Generator, revisions: int, vocab: int,
+                     redraw_tail: bool = False) -> np.ndarray:
+    """One article followed by its later revisions, each the previous
+    revision with a short edit appended."""
+    text = zipf_words(rng, int(rng.integers(20, 60)), vocab,
+                      redraw_tail=redraw_tail)
+    out = [text]
+    for _ in range(revisions - 1):
+        edit = zipf_words(rng, int(rng.integers(3, 15)), vocab,
+                          redraw_tail=redraw_tail)
+        text = np.concatenate([text, edit])
+        out.append(text)
+    return np.concatenate(out)
 
 
 def make_corpus(spec: CorpusSpec) -> List[np.ndarray]:
     rng = np.random.default_rng(spec.seed)
-    phrases = [zipf_words(rng, spec.phrase_len, spec.vocab)
+    tail = spec.redraw_tail
+    phrases = [zipf_words(rng, spec.phrase_len, spec.vocab, redraw_tail=tail)
                for _ in range(spec.n_phrases)]
     files: List[np.ndarray] = []
     for _ in range(spec.n_files):
         parts: List[np.ndarray] = []
         total = 0
         while total < spec.tokens_per_file:
-            if rng.random() < spec.phrase_rate:
+            r = rng.random()
+            if r < spec.history_rate:
+                p = revision_history(rng, spec.revisions, spec.vocab, tail)
+            elif r < spec.history_rate + spec.phrase_rate:
                 p = phrases[int(rng.integers(spec.n_phrases))]
                 # occasionally a multi-phrase motif (nested repetition)
                 if rng.random() < 0.3:
                     p = np.concatenate(
                         [p, phrases[int(rng.integers(spec.n_phrases))]])
             else:
-                p = zipf_words(rng, int(rng.integers(3, 15)), spec.vocab)
+                p = zipf_words(rng, int(rng.integers(3, 15)), spec.vocab,
+                               redraw_tail=tail)
             parts.append(p)
             total += len(p)
         files.append(np.concatenate(parts)[: spec.tokens_per_file])

@@ -19,11 +19,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map  # jax >= 0.8 canonical location
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 
 def gpipe(stage_fn: Callable, mesh: Mesh, n_stages: int,
@@ -45,9 +42,7 @@ def gpipe(stage_fn: Callable, mesh: Mesh, n_stages: int,
             # params: [1, ...] my stage's slice; mb: [M, ...] (replicated in)
             params = jax.tree.map(lambda x: x[0], params)
             idx = jax.lax.axis_index(stage_axis)
-            # jax.lax.axis_size is not available on older jax; psum of ones
-            # is the portable spelling.
-            S = jax.lax.psum(1, stage_axis)
+            S = jax.lax.axis_size(stage_axis)
             ticks = M + S - 1
 
             def tick(carry, t):
@@ -71,19 +66,12 @@ def gpipe(stage_fn: Callable, mesh: Mesh, n_stages: int,
                 return (nxt, outs), None
 
             # carries are stage-varying; the initial values come from the
-            # replicated microbatch buffer -> promote explicitly (jax>=0.8
-            # varying-manual-axes typing)
-            _pvary = getattr(jax.lax, "pvary", None)
-            if _pvary is None and hasattr(jax.lax, "pcast"):
-                def _pvary(x, axes):                 # pragma: no cover
-                    return jax.lax.pcast(x, axes, to="varying")
-            if _pvary is None:
-                # pre-varying-typing jax: replicated values are accepted as
-                # scan carries directly, no promotion needed
-                def _pvary(x, axes):
-                    return x
-            buf0 = _pvary(jnp.zeros_like(mb[0]), (stage_axis,))
-            outs0 = _pvary(jnp.zeros_like(mb), (stage_axis,))
+            # replicated microbatch buffer -> promote explicitly
+            # (varying-manual-axes typing)
+            buf0 = jax.lax.pcast(jnp.zeros_like(mb[0]), stage_axis,
+                                 to="varying")
+            outs0 = jax.lax.pcast(jnp.zeros_like(mb), stage_axis,
+                                  to="varying")
             (_, outs), _ = jax.lax.scan(tick, (buf0, outs0),
                                         jnp.arange(ticks))
             return outs[None]      # re-add the stage dim for the out spec

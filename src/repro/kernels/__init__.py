@@ -5,7 +5,7 @@
 #                          (replaces §IV-B per-thread rule walk)
 #   propagate_batched.py — fused batched ELL propagation round (delta+seen
 #                          in one launch over the [N, R, K] edge plan)
-# ops.py: jit'd wrappers + ELL-vs-segment_sum dispatch (auto interpret on
-# CPU); ref.py: pure-jnp oracles (and the fast CPU production path for the
-# batched ELL plan).
+# ops.py: jit'd wrappers + ELL-vs-segment_sum dispatch and the per-platform
+# execution path (only bincount lowers for TPU); ref.py: pure-jnp oracles
+# (and the production path of the ELL plans on TPU and CPU).
 from . import ops, ref  # noqa: F401

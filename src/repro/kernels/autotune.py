@@ -9,31 +9,32 @@ the real machine instead:
   candidate block shapes (the jnp reference form is itself a candidate, so
   the sweep also answers the ref-vs-kernel routing question) and return a
   winner entry;
-* winners persist in a small JSON cache keyed ``(backend, kind,
+* winners land in an in-process table keyed ``(backend, kind,
   shape-bucket)`` — shape buckets are the same pow2 rounding the packing
   layer uses, so one tuning run covers every pack that compiles to the
   same program;
-* ops.py consults the table first (``tuned_use_ref`` / ``tuned_blocks``)
-  and falls back to its static heuristics on a miss — an absent or stale
-  cache can never change results, only speed;
+* ops.py consults the in-process table first (``tuned_use_ref`` /
+  ``tuned_blocks``) and falls back to its shipped defaults and static
+  heuristics on a miss.  The table holds only this process's own sweeps;
+  no file steers dispatch.  A table can never change results, only speed;
 * ``sweep_xla_flags`` times a workload under named XLA flag sets in fresh
   subprocesses (flags are process-global, so in-process sweeping is
   impossible) — the flag-set dictionary follows saxml's
   ``llm_xla_flags.py`` shape: named, per-backend, composable.  Failures
-  (unknown flag on this jax build) score ``inf`` and lose, never crash;
+  (unknown flag on this jax build) score ``inf`` and lose, never crash.
+  Its children initialize JAX, so it needs the accelerator free: never run
+  it from a process that holds the chip;
 * ``hlo_profile`` revives utils/hlo_analysis.py + launch/roofline.py as
   measurement instrumentation: op histogram, FLOP/byte estimates and
   roofline classification for any jitted workload.
 
 benchmarks/bench_batch.py runs the sweeps on its corpus set and records
-``autotune/*`` rows into BENCH_batch.json; CI uploads the cache file as an
-artifact so the tuned table is inspectable per run.
+``autotune/*`` rows into BENCH_batch.json.
 """
 
 from __future__ import annotations
 
 import contextlib
-import json
 import os
 import subprocess
 import sys
@@ -41,10 +42,6 @@ import time
 from typing import Any, Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 from ._common import DEFAULT_BR, DEFAULT_FC, DEFAULT_WC, round_up_pow2
-
-CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
-DEFAULT_CACHE_PATH = "AUTOTUNE_cache.json"
-CACHE_VERSION = 1
 
 # Opt-in profiler annotations: when this env var is set (non-empty, not
 # "0"), every timed candidate runs inside a named
@@ -115,58 +112,26 @@ def _key(kind: str, bucket: Sequence[int], backend: Optional[str]) -> str:
 
 
 # ----------------------------------------------------------------------- #
-# The persistent table                                                     #
+# The tuned table                                                          #
 # ----------------------------------------------------------------------- #
-def cache_path() -> str:
-    return os.environ.get(CACHE_ENV) or DEFAULT_CACHE_PATH
-
-
-_TABLE: Optional[Dict[str, Any]] = None
-_TABLE_PATH: Optional[str] = None
-
-
-def load_table(path: Optional[str] = None) -> Dict[str, Any]:
-    """Load (and memoize) the tuned table; a missing/corrupt file is an
-    empty table — the autotuner can only ever speed things up."""
-    global _TABLE, _TABLE_PATH
-    p = path or cache_path()
-    if _TABLE is not None and _TABLE_PATH == p:
-        return _TABLE
-    table: Dict[str, Any] = {}
-    try:
-        with open(p) as f:
-            data = json.load(f)
-        if isinstance(data, dict) and data.get("version") == CACHE_VERSION:
-            table = dict(data.get("entries", {}))
-    except (OSError, ValueError):
-        table = {}
-    _TABLE, _TABLE_PATH = table, p
-    return table
-
-
-def save_table(path: Optional[str] = None) -> str:
-    p = path or cache_path()
-    table = load_table(p)
-    with open(p, "w") as f:
-        json.dump({"version": CACHE_VERSION, "entries": table}, f,
-                  indent=1, sort_keys=True)
-    return p
+# The in-process table dispatch reads (module docstring), filled by the
+# sweeps below.
+_TABLE: Dict[str, Any] = {}
 
 
 def reset_table() -> None:
-    """Drop the in-memory table memo (tests point CACHE_ENV elsewhere)."""
-    global _TABLE, _TABLE_PATH
-    _TABLE, _TABLE_PATH = None, None
+    """Empty the in-process table (tests isolate their entries with it)."""
+    _TABLE.clear()
 
 
 def put_entry(kind: str, bucket: Sequence[int], entry: Dict[str, Any],
               backend: Optional[str] = None) -> None:
-    load_table()[_key(kind, bucket, backend)] = entry
+    _TABLE[_key(kind, bucket, backend)] = entry
 
 
 def get_entry(kind: str, bucket: Sequence[int],
               backend: Optional[str] = None) -> Optional[Dict[str, Any]]:
-    return load_table().get(_key(kind, bucket, backend))
+    return _TABLE.get(_key(kind, bucket, backend))
 
 
 def tuned_use_ref(kind: str, bucket: Sequence[int],
@@ -245,9 +210,9 @@ def _sweep(candidates: Iterable[Tuple[str, Dict[str, int],
 def tune_ell_batched(weights, active, src, freq,
                      brs: Sequence[int] = BR_CANDIDATES,
                      wcs: Sequence[int] = WC_CANDIDATES,
-                     repeat: int = 3, warmup: int = 1,
-                     save: bool = False) -> Dict[str, Any]:
-    """Sweep the scalar batched ELL kernel on a real plan; persist winner."""
+                     repeat: int = 3, warmup: int = 1) -> Dict[str, Any]:
+    """Sweep the scalar batched ELL kernel on a real plan; record the
+    winner."""
     from . import ref
     from .propagate_batched import ell_propagate_batched_pallas
 
@@ -268,15 +233,12 @@ def tune_ell_batched(weights, active, src, freq,
                     weights, active, src, freq, br=br, wc=wc)))
     entry = _sweep(cands, repeat, warmup)
     put_entry("ell_batched", shape_bucket(n, rows, k), entry)
-    if save:
-        save_table()
     return entry
 
 
 def tune_ell_fused(weights0, in_deg, src, freq, max_rounds: int,
                    brs: Sequence[int] = BR_CANDIDATES,
-                   repeat: int = 3, warmup: int = 1,
-                   save: bool = False) -> Dict[str, Any]:
+                   repeat: int = 3, warmup: int = 1) -> Dict[str, Any]:
     """Sweep the fused multi-round traversal (ref fori form vs kernel)."""
     from . import ref
     from .propagate_fused import ell_frontier_fused_pallas
@@ -299,16 +261,13 @@ def tune_ell_fused(weights0, in_deg, src, freq, max_rounds: int,
                 weights0, in_deg, src, freq, max_rounds, br=br)))
     entry = _sweep(cands, repeat, warmup)
     put_entry("ell_fused", shape_bucket(n, rows, k, max_rounds), entry)
-    if save:
-        save_table()
     return entry
 
 
 def tune_ell_vector(W, active, src, freq,
                     brs: Sequence[int] = BRV_CANDIDATES,
                     fcs: Sequence[int] = FC_CANDIDATES,
-                    repeat: int = 3, warmup: int = 1,
-                    save: bool = False) -> Dict[str, Any]:
+                    repeat: int = 3, warmup: int = 1) -> Dict[str, Any]:
     """Sweep the vector-payload kernel's (row-block, F-block) shape."""
     from . import ref
     from .propagate_vector import (DEFAULT_BRV, DEFAULT_WCV,
@@ -332,8 +291,6 @@ def tune_ell_vector(W, active, src, freq,
                     W, active, src, freq, br=br, fc=fc)))
     entry = _sweep(cands, repeat, warmup)
     put_entry("ell_vector", shape_bucket(n, rows, k, F), entry)
-    if save:
-        save_table()
     return entry
 
 
@@ -346,7 +303,8 @@ def _flags_to_env(flags: Dict[str, str]) -> str:
 
 def _default_runner(workload: str, xla_flags: str) -> float:
     """Run ``workload`` (python source printing one float: seconds/call) in
-    a fresh interpreter under XLA_FLAGS; inf on any failure."""
+    a fresh interpreter under XLA_FLAGS; inf on any failure.  The child
+    takes the accelerator, so the caller must not hold it."""
     env = dict(os.environ)
     if xla_flags:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + xla_flags).strip()
@@ -366,14 +324,17 @@ def _default_runner(workload: str, xla_flags: str) -> float:
 def sweep_xla_flags(workload: str,
                     backend: Optional[str] = None,
                     flag_sets: Optional[Dict[str, Dict[str, str]]] = None,
-                    runner: Optional[Callable[[str, str], float]] = None,
-                    save: bool = False) -> Dict[str, Any]:
+                    runner: Optional[Callable[[str, str], float]] = None
+                    ) -> Dict[str, Any]:
     """Time ``workload`` under every named flag set for ``backend``.
 
     ``runner(workload, xla_flags) -> seconds`` is injectable for tests; the
     default spawns a fresh interpreter per set (XLA flags are read once per
-    process).  The winner persists under kind "xla_flags" keyed by a hash
-    bucket of the workload source, and "default" is always a candidate so
+    process).  Those interpreters initialize JAX on the default backend, so
+    the sweep needs the accelerator free: a chip belongs to one process,
+    and a child started by a process that holds it fails or hangs (and
+    scores ``inf`` here).  The winner is recorded under kind "xla_flags" keyed
+    by a hash bucket of the workload source, and "default" is always a candidate so
     the tuned flags can never lose to shipping none.
     """
     b = backend or backend_name()
@@ -395,8 +356,6 @@ def sweep_xla_flags(workload: str,
     import zlib
     bucket = (zlib.crc32(workload.encode()) & 0xffff,)
     put_entry("xla_flags", bucket, entry, backend=b)
-    if save:
-        save_table()
     return entry
 
 

@@ -9,9 +9,18 @@ MXU.  Conflict-free and deterministic by construction — every (tile, bin
 block) contribution is a 128-aligned matmul.
 
 Layout:
-  ids   [NT, TN] int32   (flattened input padded/reshaped by ops.py)
-  vals  [NT, TN] float32
-  out   [1, V]   float32 (V padded to a multiple of BV)
+  ids   [NT, 1, TN] int32   (flattened input padded/reshaped by ops.py)
+  vals  [NT, 1, TN] float32
+  out   [1, V]      float32 (V padded to a multiple of BV)
+
+The unit middle axis makes each input block's last two dims ``(1, TN)``
+equal the array's, which Mosaic requires of a block that is not (8, 128)
+aligned; TN and BV are multiples of 128.  The one-hot tile is built
+transposed, ``[BV, TN]`` with bins on sublanes, so the ids row broadcasts
+down the sublanes instead of being relaid out as a column, and the MXU
+contracts the TN axis of both operands.  The contraction runs at fp32
+precision (``Precision.HIGHEST``): at the TPU default an f32 matmul rounds
+its operands to bfloat16, and counts above 256 would lose exactness.
 
 Grid = (V // BV, NT): for a fixed bin block i we sweep all input tiles j,
 accumulating into the same VMEM-resident output block (revisiting grid
@@ -41,13 +50,15 @@ def _kernel(ids_ref, vals_ref, out_ref, *, bv: int):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     i = pl.program_id(0)                       # bin-block index
-    ids = ids_ref[0, :]                        # [TN]
-    vals = vals_ref[0, :]                      # [TN]
-    cols = i * bv + jax.lax.broadcasted_iota(jnp.int32, (1, bv), 1)[0]
-    onehot = (ids[:, None] == cols[None, :]).astype(jnp.float32)   # [TN, BV]
-    # [1, TN] @ [TN, BV] -> [1, BV] on the MXU
-    out_ref[...] += jnp.dot(vals[None, :], onehot,
-                            preferred_element_type=jnp.float32)
+    ids = ids_ref[0]                           # [1, TN]
+    vals = vals_ref[0]                         # [1, TN]
+    bins = i * bv + jax.lax.broadcasted_iota(jnp.int32, (bv, ids.shape[1]), 0)
+    onehot_t = (bins == ids).astype(jnp.float32)                  # [BV, TN]
+    # [1, TN] x [BV, TN]^T -> [1, BV] on the MXU
+    out_ref[...] += jax.lax.dot_general(
+        vals, onehot_t, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 def weighted_bincount_pallas(ids: jnp.ndarray, vals: jnp.ndarray, nbins: int,
@@ -70,8 +81,8 @@ def _weighted_bincount_jit(ids, vals, nbins: int, tn: int, bv: int,
     ids_p = jnp.pad(ids.astype(jnp.int32), (0, n_pad), constant_values=-1)
     vals_p = jnp.pad(vals.astype(jnp.float32), (0, n_pad))
     nt = ids_p.shape[0] // tn
-    ids2 = ids_p.reshape(nt, tn)
-    vals2 = vals_p.reshape(nt, tn)
+    ids3 = ids_p.reshape(nt, 1, tn)
+    vals3 = vals_p.reshape(nt, 1, tn)
     v_pad = (-nbins) % bv
     vtot = nbins + v_pad
 
@@ -79,11 +90,11 @@ def _weighted_bincount_jit(ids, vals, nbins: int, tn: int, bv: int,
         functools.partial(_kernel, bv=bv),
         grid=(vtot // bv, nt),
         in_specs=[
-            pl.BlockSpec((1, tn), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, tn), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, 1, tn), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((1, 1, tn), lambda i, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bv), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, vtot), jnp.float32),
         interpret=interpret,
-    )(ids2, vals2)
+    )(ids3, vals3)
     return out[0, :nbins]

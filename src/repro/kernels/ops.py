@@ -1,7 +1,7 @@
 """Jit'd public wrappers + dispatch layer for the Pallas kernels.
 
-``interpret`` defaults to auto: real lowering on TPU, interpret mode on CPU
-(the assignment's validation mode).  Wrappers fall back to the jnp
+``interpret`` defaults to auto: the platform picks the execution path per
+kernel (DESIGN note below).  Wrappers fall back to the jnp
 reference for degenerate shapes where a kernel launch is pure overhead; the
 dispatch predicates are exposed (``bincount_use_ref`` / ``ell_use_ref`` /
 ``ell_batched_use_ref`` / ``bincount_batch_rows``) so tests can assert the
@@ -13,11 +13,24 @@ the dense ``[N, R, K]`` ELL edge plan (gather form, no scatter — see
 propagate_batched.py) or stay on the COO segment_sum path.  The predicate
 is an occupancy model over (edge count, plan width K — the max in/out fan
 bucketed to a power of two, batch width N): very sparse or very wide plans
-waste K-proportional work, tiny batches never amortize a launch.  Within
-``ell_propagate_batched`` the second routing decision is platform-shaped:
-TPU lowers the Pallas kernel; CPU production traffic takes the jnp form of
-the same plan (interpret-mode emulation is pure overhead — interpret=True
-remains available as the validation oracle).
+waste K-proportional work, tiny batches never amortize a launch.
+
+DESIGN — execution path per kernel (:func:`exec_path`, a static decision
+from the platform; counted as
+``repro_kernel_dispatch_total{decision="exec:*"}``
+with path ``pallas_compiled`` / ``pallas_interpret`` / ``jnp_ref``):
+
+* TPU lowers the bincount kernel.  The four ELL kernels gather
+  ``weight[src]`` from VMEM by arbitrary index, and Mosaic lowers a gather
+  only within one source vreg ("Multiple source vregs along gather
+  dimension"), so on TPU they run as the jnp form of the same plan (an XLA
+  gather).  No compile error is caught at run time: the route is chosen
+  before anything is traced (tests/test_tpu_compile.py holds both facts).
+* CPU production takes the jnp form of the ELL plans (interpret-mode
+  emulation is pure overhead); bincount and row sums keep their
+  interpret-mode kernels there.  ``interpret=True`` remains the validation
+  oracle everywhere, and the forced-interpret CI lane pushes every CPU call
+  through the kernels (it raises on a TPU — _common.force_interpret).
 
 Weight-vector size routes nothing: both ELL kernels stream the weight
 vector through VMEM in grid-blocked chunks (propagate.py DESIGN note), so
@@ -34,8 +47,7 @@ from repro.obs import global_registry
 
 from . import autotune, ref
 from ._common import (DEFAULT_BR, DEFAULT_WC, force_interpret,
-                      on_tpu as _on_tpu, reset_backend_cache,
-                      resolve_interpret as _interp)
+                      on_tpu as _on_tpu, reset_backend_cache)
 from .bincount import weighted_bincount_pallas
 from .propagate import ell_row_sums_pallas
 from .propagate_batched import ell_propagate_batched_pallas
@@ -48,6 +60,7 @@ __all__ = [
     "ell_frontier_fused", "bincount_use_ref", "bincount_batch_rows",
     "ell_use_ref", "ell_batched_use_ref", "ell_fused_use_kernel",
     "ell_vector_plan_ok", "reset_backend_cache", "force_interpret",
+    "exec_path", "KERNELS",
 ]
 
 # Below these sizes a kernel launch is pure overhead.
@@ -91,11 +104,36 @@ def _count_tuned(kind: str, result: str) -> None:
         ("kind", "result")).labels(kind, result).inc()
 
 
-def _exec_path(interpret) -> str:
-    """Which of the three execution modes a wrapper is about to take."""
-    if _use_jnp_ref(interpret):
-        return "jnp_ref"
-    return "pallas_interpret" if _interp(interpret) else "pallas_compiled"
+#: Every Pallas kernel behind a wrapper here.
+KERNELS = ("bincount", "ell_row_sums", "ell_batched", "ell_vector",
+           "ell_fused")
+#: Kernels Mosaic lowers for TPU (module DESIGN note); every other kernel
+#: runs its jnp form there under auto routing.
+TPU_LOWERED_KERNELS = frozenset({"bincount"})
+#: Kernels whose CPU production form is the jnp reference.
+CPU_JNP_KERNELS = frozenset({"ell_batched", "ell_vector", "ell_fused"})
+
+
+def exec_path(kernel: str, interpret=None) -> str:
+    """Which of the three execution modes ``kernel`` takes:
+    ``pallas_compiled``, ``pallas_interpret`` or ``jnp_ref``.  Explicit
+    ``interpret`` is always honored; ``None`` routes by platform (module
+    DESIGN note)."""
+    if interpret is not None:
+        return "pallas_interpret" if interpret else "pallas_compiled"
+    if force_interpret():                   # CPU lane; raises on a TPU
+        return "pallas_interpret"
+    if _on_tpu():
+        return ("pallas_compiled" if kernel in TPU_LOWERED_KERNELS
+                else "jnp_ref")
+    return "jnp_ref" if kernel in CPU_JNP_KERNELS else "pallas_interpret"
+
+
+def _dispatch(kernel: str, interpret) -> str:
+    """:func:`exec_path`, counted on the dispatch metric."""
+    path = exec_path(kernel, interpret)
+    _count_dispatch(f"exec:{kernel}", path)
+    return path
 
 
 def bincount_use_ref(n: int, nbins: int) -> bool:
@@ -168,15 +206,9 @@ def ell_fused_use_kernel(rows: int) -> bool:
 def ell_vector_plan_ok(n: int, rows: int, k: int, f: int) -> bool:
     """True when the vector-payload [N, rows, K] x [R, F] round fits the
     dense-plan budget (the gather materializes N*rows*K*F contributions)."""
-    return n * rows * k * max(f, 1) <= ELL_PLAN_MAX_ENTRIES
-
-
-def _use_jnp_ref(interpret) -> bool:
-    """True when production dispatch should take the jnp reference form:
-    CPU with auto routing (interpret-mode kernel emulation is pure
-    overhead) — unless the forced-interpret CI lane is on, which exists
-    precisely to push production traffic through the Pallas code paths."""
-    return interpret is None and not _on_tpu() and not force_interpret()
+    ok = n * rows * k * max(f, 1) <= ELL_PLAN_MAX_ENTRIES
+    _count_dispatch("vector_plan", "ell" if ok else "segment_sum")
+    return ok
 
 
 def _blocks(kind: str, bucket, defaults: dict) -> dict:
@@ -197,8 +229,9 @@ def weighted_bincount(ids: jnp.ndarray, vals: jnp.ndarray, nbins: int,
         return jnp.zeros(nbins, jnp.float32)
     if bincount_use_ref(ids.shape[0], nbins):
         return ref.weighted_bincount_ref(ids, vals, nbins)
+    path = _dispatch("bincount", interpret)
     return weighted_bincount_pallas(ids, vals, nbins,
-                                    interpret=_interp(interpret))
+                                    interpret=path == "pallas_interpret")
 
 
 def weighted_bincount_batched(ids: jnp.ndarray, vals: jnp.ndarray,
@@ -235,6 +268,7 @@ def weighted_bincount_batched(ids: jnp.ndarray, vals: jnp.ndarray,
         return flat.reshape(rows, nbins)
 
     rows = bincount_batch_rows(n, nbins)
+    _count_dispatch("bincount_batch", "flat" if rows >= n else "chunked")
     if rows >= n:
         return flat_chunk(ids, vals)
     return jnp.concatenate(
@@ -272,8 +306,11 @@ def ell_row_sums(weights: jnp.ndarray, src: jnp.ndarray, freq: jnp.ndarray,
         return jnp.zeros(0, jnp.float32)
     if ell_use_ref(weights.shape[0], src.shape[0]):
         return ref.ell_row_sums_ref(weights, src, freq)
+    path = _dispatch("ell_row_sums", interpret)
+    if path == "jnp_ref":
+        return ref.ell_row_sums_ref(weights, src, freq)
     return ell_row_sums_pallas(weights, src, freq,
-                               interpret=_interp(interpret))
+                               interpret=path == "pallas_interpret")
 
 
 def ell_propagate_batched(weights: jnp.ndarray, active: jnp.ndarray,
@@ -282,10 +319,9 @@ def ell_propagate_batched(weights: jnp.ndarray, active: jnp.ndarray,
     """One fused propagation round over the dense [N, rows, K] ELL plan.
 
     Returns ``(delta, seen)`` — both [N, rows] float32; see
-    propagate_batched.py for the exact semantics.  Routing: TPU lowers the
-    Pallas kernel; on CPU (interpret=None) the jnp form of the same plan is
-    the production path, and interpret=True forces the interpret-mode
-    kernel as the validation oracle.
+    propagate_batched.py for the exact semantics.  Routing (interpret=None,
+    module DESIGN note): the jnp form of the same plan on TPU and CPU;
+    interpret=True runs the interpret-mode kernel as the validation oracle.
     """
     if src.ndim != 3 or freq.shape != src.shape:
         raise ValueError(f"expected matching [N, rows, K] plans, got "
@@ -294,13 +330,13 @@ def ell_propagate_batched(weights: jnp.ndarray, active: jnp.ndarray,
     if n == 0 or rows == 0 or k == 0:
         z = jnp.zeros((n, rows), jnp.float32)
         return z, z
-    _count_dispatch("exec:ell_batched", _exec_path(interpret))
-    if _use_jnp_ref(interpret):
+    path = _dispatch("ell_batched", interpret)
+    if path == "jnp_ref":
         return ref.ell_propagate_batched_ref(weights, active, src, freq)
     blocks = _blocks("ell_batched", autotune.shape_bucket(n, rows, k),
                      {"br": DEFAULT_BR, "wc": DEFAULT_WC})
     return ell_propagate_batched_pallas(weights, active, src, freq,
-                                        interpret=_interp(interpret),
+                                        interpret=path == "pallas_interpret",
                                         **blocks)
 
 
@@ -311,10 +347,9 @@ def ell_propagate_vector(W: jnp.ndarray, active: jnp.ndarray,
 
     W: [N, R, F] per-file payload; returns ``(delta [N, rows, F],
     seen [N, rows])`` — the per-file traversals' ELL round (see
-    propagate_vector.py).  Routing mirrors ``ell_propagate_batched``: TPU
-    lowers the Pallas kernel, CPU production takes the jnp gather form,
-    interpret=True (or the forced-interpret lane) runs the interpret-mode
-    kernel as the validation oracle.
+    propagate_vector.py).  Routing mirrors ``ell_propagate_batched``: the
+    jnp gather form in production, interpret=True (or the forced-interpret
+    lane) runs the interpret-mode kernel as the validation oracle.
     """
     if src.ndim != 3 or freq.shape != src.shape:
         raise ValueError(f"expected matching [N, rows, K] plans, got "
@@ -325,8 +360,8 @@ def ell_propagate_vector(W: jnp.ndarray, active: jnp.ndarray,
     if n == 0 or rows == 0 or k == 0:
         return (jnp.zeros((n, rows, W.shape[-1]), jnp.float32),
                 jnp.zeros((n, rows), jnp.float32))
-    _count_dispatch("exec:ell_vector", _exec_path(interpret))
-    if _use_jnp_ref(interpret):
+    path = _dispatch("ell_vector", interpret)
+    if path == "jnp_ref":
         return ref.ell_propagate_vector_ref(W, active, src, freq)
     from .propagate_vector import DEFAULT_BRV, DEFAULT_WCV
     from ._common import DEFAULT_FC
@@ -334,7 +369,7 @@ def ell_propagate_vector(W: jnp.ndarray, active: jnp.ndarray,
         "ell_vector", autotune.shape_bucket(n, rows, k, W.shape[-1]),
         {"br": DEFAULT_BRV, "wc": DEFAULT_WCV, "fc": DEFAULT_FC})
     return ell_propagate_vector_pallas(W, active, src, freq,
-                                       interpret=_interp(interpret),
+                                       interpret=path == "pallas_interpret",
                                        **blocks)
 
 
@@ -348,9 +383,9 @@ def ell_frontier_fused(weights0: jnp.ndarray, in_deg: jnp.ndarray,
     the frontier round count (the DAG's ``num_levels`` is exact).  Returns
     weights [N, R] — or ``(weights, rounds [N])`` when ``with_rounds``.
     Callers must pre-gate with ``ell_fused_use_kernel(R)`` (VMEM state
-    residency); routing follows ``ell_propagate_batched``: CPU production
-    runs the jitted fori_loop reference (one dispatch, no per-round
-    convergence test — the same structural-tax win in jnp form), TPU and
+    residency); routing follows ``ell_propagate_batched``: production on
+    TPU and CPU runs the jitted fori_loop reference (one dispatch, no
+    per-round convergence test — the same structural-tax win in jnp form),
     the interpret lanes run the Pallas kernel.
     """
     if src.ndim != 3 or freq.shape != src.shape:
@@ -360,8 +395,8 @@ def ell_frontier_fused(weights0: jnp.ndarray, in_deg: jnp.ndarray,
     if n == 0 or rows == 0 or k == 0:
         w = weights0.astype(jnp.float32)
         return (w, jnp.zeros(n, jnp.int32)) if with_rounds else w
-    _count_dispatch("exec:ell_fused", _exec_path(interpret))
-    if _use_jnp_ref(interpret):
+    path = _dispatch("ell_fused", interpret)
+    if path == "jnp_ref":
         return ref.ell_frontier_fused_ref(weights0, in_deg, src, freq,
                                           max_rounds,
                                           with_rounds=with_rounds)
@@ -370,6 +405,6 @@ def ell_frontier_fused(weights0: jnp.ndarray, in_deg: jnp.ndarray,
                      {"br": DEFAULT_BR})
     w, rounds = ell_frontier_fused_pallas(weights0, in_deg, src, freq,
                                           max_rounds,
-                                          interpret=_interp(interpret),
+                                          interpret=path == "pallas_interpret",
                                           **blocks)
     return (w, rounds) if with_rounds else w

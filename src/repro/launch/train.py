@@ -49,6 +49,7 @@ def main() -> None:
             coordinator_address=args.coordinator,
             num_processes=args.num_hosts, process_id=args.host_id)
 
+    from repro.launch.mesh import auto_mesh
     from repro.configs import get_config
     from repro.data import BatchPipeline, CompressedCorpus, synthetic
     from repro.distributed import (batch_shardings, default_rules,
@@ -76,7 +77,7 @@ def main() -> None:
         d, m = (int(x) for x in args.mesh.split("x"))
     else:
         d, m = n_dev, 1
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = auto_mesh((d, m), ("data", "model"))
     rules = default_rules(mesh)
 
     boxed = init_lm(jax.random.PRNGKey(0), cfg)

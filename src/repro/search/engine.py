@@ -24,7 +24,10 @@ compressed domain:
    an FMA and the result stops being bit-identical to the numpy oracle;
    the loop-carried add keeps every operation an exactly-specified IEEE
    elementwise op (tests/test_differential.py asserts bit equality of
-   both rankings and scores).
+   both rankings and scores).  Division is the exception: TPU's float32
+   divide is close to the quotient but not correctly rounded, so the BM25
+   quotient goes through :func:`div_rn`, which rounds it as IEEE division
+   does on every backend.
 4. **Sharded packs** (``gb.mesh``) run the same scoring program through
    ``shard_map`` (:func:`repro.core.batch._sharded_program`): each device
    ranks its own corpus rows — per-shard top-k, no cross-device traffic —
@@ -63,6 +66,55 @@ __all__ = ["batched_search", "search_corpus", "search_index_topk",
 # ----------------------------------------------------------------------- #
 # The jitted scoring + top-k program                                       #
 # ----------------------------------------------------------------------- #
+def _two_prod(x, y):
+    """``(p, e)`` with ``p + e == x * y`` exactly (Dekker's product with
+    Veltkamp splitting: plain float32 multiplies and adds, no FMA)."""
+    def split(v):
+        c = v * jnp.float32(4097.0)
+        hi = c - (c - v)
+        return hi, v - hi
+
+    p = x * y
+    xh, xl = split(x)
+    yh, yl = split(y)
+    return p, ((xh * yh - p) + xh * yl + xl * yh) + xl * yl
+
+
+def _residual(a, b, q):
+    """``a - q*b`` exactly, for ``q`` within a factor of two of ``a / b``
+    (then ``a - p`` is exact by Sterbenz's lemma)."""
+    p, e = _two_prod(q, b)
+    return (a - p) - e
+
+
+def round_quotient(a, b, q):
+    """The float32 nearest ``a / b`` (ties to even), given an approximate
+    quotient ``q``: one Newton step on the exact residual brings ``q``
+    within an ulp, then of ``q`` and its two neighbours the one with the
+    smallest ``|a - q*b|`` wins.  ``a >= 0``, ``b > 0``, normal
+    magnitudes."""
+    def even(c):
+        return (jax.lax.bitcast_convert_type(c, jnp.int32) & 1) == 0
+
+    best = q + _residual(a, b, q) / b
+    r_best = jnp.abs(_residual(a, b, best))
+    for c in (jnp.nextafter(best, jnp.float32(-jnp.inf)),
+              jnp.nextafter(best, jnp.float32(jnp.inf))):
+        r = jnp.abs(_residual(a, b, c))
+        take = (r < r_best) | ((r == r_best) & even(c) & ~even(best))
+        best = jnp.where(take, c, best)
+        r_best = jnp.where(take, r, r_best)
+    return best
+
+
+def div_rn(a, b):
+    """``a / b`` rounded as IEEE float32 division rounds it, on any
+    backend (module DESIGN note, step 3): on a TPU v5e, about 27% of
+    random BM25-shaped float32 quotients come out one or more ulp away
+    from numpy's."""
+    return round_quotient(a, b, a / b)
+
+
 def _score_topk_impl(tv, terms, idf_q, norm, fvalid, k=None, scheme=None):
     """Score ``[n, F]`` docs against ``[n, Q]`` term slots and rank top-k.
 
@@ -75,7 +127,7 @@ def _score_topk_impl(tv, terms, idf_q, norm, fvalid, k=None, scheme=None):
     """
     tf_q = jnp.take_along_axis(tv, terms[:, None, :], axis=2)   # [n, F, Q]
     if scheme == "bm25":
-        quot = (tf_q * jnp.float32(K1P1)) / (tf_q + norm[:, :, None])
+        quot = div_rn(tf_q * jnp.float32(K1P1), tf_q + norm[:, :, None])
     elif scheme == "tfidf":
         quot = tf_q
     else:

@@ -22,6 +22,7 @@ from repro.configs import get_config
 from repro.data import BatchPipeline, CompressedCorpus, synthetic
 from repro.distributed import (batch_shardings, default_rules,
                                param_shardings, reshard_tree)
+from repro.launch.mesh import auto_mesh
 from repro.models import init_lm, reduced, unbox
 from repro.training import AdamW, make_train_step
 
@@ -49,7 +50,7 @@ def test_sharded_equals_single():
     p1, _, m1 = jax.jit(step)(params, opt.init(params), batch)
 
     # 2x4 mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     rules = default_rules(mesh)
     psh = param_shardings(axes, params, mesh, rules)
     params_s = jax.tree.map(jax.device_put, params, psh)
@@ -92,7 +93,7 @@ def test_elastic():
     step = jax.jit(make_train_step(cfg, opt))
 
     def run(mesh_shape, start, stop, params, opt_state, losses):
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = auto_mesh(mesh_shape, ("data", "model"))
         rules = default_rules(mesh)
         params = reshard_tree(params, axes, mesh, rules)
         opt_state = type(opt_state)(

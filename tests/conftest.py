@@ -6,14 +6,6 @@ import zlib
 # dry-run) forces 512 host devices, in its own process.
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# A bench run leaves a machine-tuned AUTOTUNE_cache.json in the repo root;
-# the suite must not pick it up (tuned routing entries would make dispatch
-# assertions depend on whatever was last benchmarked here).  Tests that
-# exercise the tuned table point this env var at their own tmp file.
-os.environ.setdefault("REPRO_AUTOTUNE_CACHE",
-                      os.path.join(os.path.dirname(__file__),
-                                   "_no_autotune_cache.json"))
-
 import numpy as np
 import pytest
 

@@ -148,3 +148,26 @@ def test_synthetic_table2_shapes():
         files = synthetic.make_table2_corpus(name)
         assert len(files) == spec.n_files
         assert all(len(f) == spec.tokens_per_file for f in files)
+
+
+def test_synthetic_redrawn_tail_and_revision_histories():
+    """A redrawn zipf tail stays inside the vocabulary without piling the
+    tail's mass onto the last word id, and revision histories deepen the
+    rule DAG well past the phrase model's."""
+    rng = np.random.default_rng(0)
+    clipped = synthetic.zipf_words(rng, 20_000, 500)
+    redrawn = synthetic.zipf_words(rng, 20_000, 500, redraw_tail=True)
+    assert redrawn.min() >= 0 and redrawn.max() < 500
+    assert (clipped == 499).sum() > 50 * max((redrawn == 499).sum(), 1)
+
+    base = synthetic.CorpusSpec("h", n_files=2, tokens_per_file=6000,
+                                vocab=5000, phrase_rate=0.6, n_phrases=30,
+                                phrase_len=8, seed=1, redraw_tail=True)
+    deep = dataclasses.replace(base, history_rate=0.01, revisions=24)
+    levels = {}
+    for spec in (base, deep):
+        files = synthetic.make_corpus(spec)
+        assert all(len(f) == spec.tokens_per_file for f in files)
+        levels[spec.revisions] = CompressedCorpus.build(
+            files, spec.vocab).ga.num_levels
+    assert levels[24] >= 24 > levels[0]

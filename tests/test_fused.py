@@ -9,6 +9,8 @@ analytics-level equivalence of the same paths lives in test_ell_batched.py
 and test_differential.py.
 """
 
+import json
+
 import numpy as np
 import pytest
 
@@ -215,11 +217,11 @@ def test_forced_interpret_lane_routes_to_kernels(rng, monkeypatch):
     monkeypatch.delenv(FORCE_INTERPRET_ENV, raising=False)
     assert not force_interpret()
     assert resolve_interpret(None) is True        # CPU auto => interpret
-    assert ops._use_jnp_ref(None)                 # ...but prod takes jnp
+    assert ops.exec_path("ell_batched", None) == "jnp_ref"  # ...prod: jnp
 
     monkeypatch.setenv(FORCE_INTERPRET_ENV, "1")
     assert force_interpret()
-    assert not ops._use_jnp_ref(None)             # lane: kernels run
+    assert ops.exec_path("ell_batched", None) == "pallas_interpret"
     calls = []
     real = ops.ell_propagate_batched_pallas
 
@@ -240,8 +242,10 @@ def test_forced_interpret_lane_routes_to_kernels(rng, monkeypatch):
 
 def test_tpu_production_never_runs_interpret(rng, monkeypatch):
     """Satellite regression: on TPU, production traffic (interpret=None)
-    must reach the Pallas entry with interpret=False — the old
-    ``interpret: bool = True`` jit default silently emulated every kernel.
+    never emulates a kernel — the old ``interpret: bool = True`` jit
+    default silently did.  The bincount kernel reaches its Pallas entry
+    with interpret=False; the ELL kernels, which Mosaic cannot lower, take
+    the jnp form of the same plan and never reach the Pallas entry.
     Backend is faked via the revocable probe (reset_backend_cache)."""
 
     class _Dev:
@@ -250,11 +254,16 @@ def test_tpu_production_never_runs_interpret(rng, monkeypatch):
     captured = {}
 
     def fake_pallas(w, a, s, f, br=0, wc=0, interpret=None):
-        captured["interpret"] = interpret
+        captured["ell"] = interpret
         return ref.ell_propagate_batched_ref(w, a, s, f)
+
+    def fake_bincount(ids, vals, nbins, interpret=None):
+        captured["bincount"] = interpret
+        return ref.weighted_bincount_ref(ids, vals, nbins)
 
     monkeypatch.delenv(FORCE_INTERPRET_ENV, raising=False)
     monkeypatch.setattr(ops, "ell_propagate_batched_pallas", fake_pallas)
+    monkeypatch.setattr(ops, "weighted_bincount_pallas", fake_bincount)
     monkeypatch.setattr(ops.jax, "devices", lambda: [_Dev()])
     ops.reset_backend_cache()
     try:
@@ -263,8 +272,13 @@ def test_tpu_production_never_runs_interpret(rng, monkeypatch):
         w = jnp.ones((1, 70), jnp.float32)
         src = jnp.zeros((1, 70, 2), jnp.int32)
         frq = jnp.ones((1, 70, 2), jnp.float32)
-        ops.ell_propagate_batched(w, w, src, frq)
-        assert captured["interpret"] is False
+        got = ops.ell_propagate_batched(w, w, src, frq)
+        want = ref.ell_propagate_batched_ref(w, w, src, frq)
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(want[0]))
+        ops.weighted_bincount(jnp.zeros(100, jnp.int32),
+                              jnp.ones(100, jnp.float32), 16)
+        assert captured == {"bincount": False}
     finally:
         monkeypatch.undo()
         ops.reset_backend_cache()
@@ -273,13 +287,10 @@ def test_tpu_production_never_runs_interpret(rng, monkeypatch):
 
 # ------------------------------------------------------------ autotune --
 @pytest.fixture
-def tuned_table(tmp_path, monkeypatch):
-    """Isolated autotune cache: point CACHE_ENV at a temp file and drop
-    the module memo on both entry and exit."""
-    path = tmp_path / "tuned.json"
-    monkeypatch.setenv(autotune.CACHE_ENV, str(path))
+def tuned_table():
+    """Isolated autotune table: empty on both entry and exit."""
     autotune.reset_table()
-    yield path
+    yield
     autotune.reset_table()
 
 
@@ -292,19 +303,30 @@ def test_autotune_table_roundtrip(tuned_table):
     entry = {"winner": "br128_wc65536", "use_ref": False,
              "blocks": {"br": 128, "wc": 1 << 16}, "us": 10.0}
     autotune.put_entry("ell_batched", bucket, entry)
-    autotune.save_table()
-    autotune.reset_table()                        # force reload from disk
     got = autotune.get_entry("ell_batched", bucket)
     assert got["winner"] == "br128_wc65536"
     assert autotune.tuned_use_ref("ell_batched", bucket) is False
     assert autotune.tuned_blocks("ell_batched", bucket) == \
         {"br": 128, "wc": 1 << 16}
-
-
-def test_autotune_corrupt_cache_is_empty(tuned_table):
-    tuned_table.write_text("{not json")
+    # entries are per backend: a table tuned elsewhere never applies here
+    autotune.put_entry("ell_fused", bucket, entry, backend="other")
+    assert autotune.get_entry("ell_fused", bucket) is None
     autotune.reset_table()
-    assert autotune.load_table() == {}            # never crashes dispatch
+    assert autotune.get_entry("ell_batched", bucket) is None
+
+
+def test_dispatch_ignores_files_in_the_checkout(tuned_table, tmp_path,
+                                                monkeypatch):
+    """Block sizes come from the shipped defaults and this process's own
+    sweeps only: a tuned-table file lying in the working directory (as an
+    older autotuner wrote one) never steers dispatch."""
+    bucket = autotune.shape_bucket(2, 100, 3)
+    key = f"{autotune.backend_name()}|ell_batched|2x128x4"
+    (tmp_path / "AUTOTUNE_cache.json").write_text(json.dumps(
+        {"version": 1, "entries": {key: {"blocks": {"br": 8}}}}))
+    monkeypatch.chdir(tmp_path)
+    assert autotune.tuned_blocks("ell_batched", bucket) == {}
+    assert ops._blocks("ell_batched", bucket, {"br": 256}) == {"br": 256}
 
 
 def test_tuned_use_ref_overrides_heuristics(tuned_table):
@@ -349,7 +371,7 @@ def test_tuned_blocks_feed_kernel_dispatch(tuned_table, rng, monkeypatch):
 
 def test_tune_sweeps_record_and_persist(tuned_table, rng):
     """The three kernel sweeps run real candidates (interpret mode on CPU)
-    and persist winner entries the dispatch layer can read back."""
+    and record winner entries the dispatch layer reads back."""
     n, R, K, F = 1, 70, 2, 3
     src = jnp.asarray(rng.integers(0, R, (n, R, K)).astype(np.int32))
     frq = jnp.asarray(rng.integers(0, 3, (n, R, K)).astype(np.float32))
@@ -364,7 +386,7 @@ def test_tune_sweeps_record_and_persist(tuned_table, rng):
                                  repeat=1, warmup=0)
     W = jnp.asarray(rng.integers(0, 3, (n, R, F)).astype(np.float32))
     e3 = autotune.tune_ell_vector(W, act, src, frq, brs=(8,), fcs=(4,),
-                                  repeat=1, warmup=0, save=True)
+                                  repeat=1, warmup=0)
     for e in (e1, e2, e3):
         assert {"winner", "blocks", "use_ref", "us", "table_us"} <= set(e)
         assert e["us"] <= min(e["table_us"].values()) + 1e-9
@@ -372,9 +394,6 @@ def test_tune_sweeps_record_and_persist(tuned_table, rng):
         "ell_batched", autotune.shape_bucket(n, R, K)) is not None
     assert autotune.get_entry(
         "ell_fused", autotune.shape_bucket(n, R, K, 4)) is not None
-    assert autotune.get_entry(
-        "ell_vector", autotune.shape_bucket(n, R, K, F)) is not None
-    autotune.reset_table()                        # save=True hit the disk
     assert autotune.get_entry(
         "ell_vector", autotune.shape_bucket(n, R, K, F)) is not None
 

@@ -204,6 +204,52 @@ def test_on_tpu_cache_resettable(monkeypatch):
         assert ops._on_tpu() is False
 
 
+def test_on_tpu_propagates_backend_errors(monkeypatch):
+    """A backend that fails to start must fail the caller, not answer
+    "not a TPU" and send traffic to the CPU forms."""
+
+    def broken():
+        raise RuntimeError("TPU backend failed to initialize")
+
+    try:
+        monkeypatch.setattr(ops.jax, "devices", broken)
+        ops.reset_backend_cache()
+        with pytest.raises(RuntimeError, match="failed to initialize"):
+            ops._on_tpu()
+    finally:
+        monkeypatch.undo()
+        ops.reset_backend_cache()
+        assert ops._on_tpu() is False
+
+
+def test_force_interpret_raises_on_tpu(monkeypatch):
+    """REPRO_FORCE_INTERPRET is a CPU lane: on a TPU it is an error, never
+    a silent switch to kernel emulation."""
+    from repro.kernels._common import (FORCE_INTERPRET_ENV, force_interpret,
+                                       resolve_interpret)
+
+    class _Dev:
+        platform = "tpu"
+
+    try:
+        monkeypatch.setattr(ops.jax, "devices", lambda: [_Dev()])
+        monkeypatch.setenv(FORCE_INTERPRET_ENV, "1")
+        ops.reset_backend_cache()
+        with pytest.raises(RuntimeError, match=FORCE_INTERPRET_ENV):
+            force_interpret()
+        with pytest.raises(RuntimeError, match=FORCE_INTERPRET_ENV):
+            resolve_interpret(None)
+        with pytest.raises(RuntimeError, match=FORCE_INTERPRET_ENV):
+            ops.weighted_bincount(jnp.zeros(100, jnp.int32),
+                                  jnp.ones(100, jnp.float32), 16)
+        monkeypatch.setenv(FORCE_INTERPRET_ENV, "0")
+        assert force_interpret() is False          # unset lane: no error
+    finally:
+        monkeypatch.undo()
+        ops.reset_backend_cache()
+        assert ops._on_tpu() is False
+
+
 def test_bincount_batched_empty_and_bad_shapes():
     assert ops.weighted_bincount_batched(
         jnp.zeros((3, 0), jnp.int32), jnp.zeros((3, 0), jnp.float32),

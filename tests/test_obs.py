@@ -22,6 +22,7 @@ import re
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -517,6 +518,35 @@ def test_kernel_dispatch_counters():
     fused = {k[1]: c.value for k, c in fam.children()
              if k[0] == "fused_vs_per_round"}
     assert fused.get("fused", 0) >= 1
+
+
+def test_vocabulary_gate_dispatch_counters(monkeypatch):
+    """The batched bincount's flat-vs-chunked decision and the per-file
+    vector-plan gate are counted, so a run shows which path its widths
+    took."""
+    fam = global_registry().counter("repro_kernel_dispatch_total", "",
+                                    ("decision", "path"))
+
+    def count(decision):
+        return {k[1]: c.value for k, c in fam.children() if k[0] == decision}
+
+    before = count("bincount_batch")
+    monkeypatch.setattr(kops, "BINCOUNT_BATCH_FLAT_LIMIT", 256)
+    ids = jnp.asarray(np.arange(32).reshape(4, 8) % 128, jnp.int32)
+    vals = jnp.ones((4, 8), jnp.float32)
+    chunked = kops.weighted_bincount_batched(ids, vals, 128)   # 2 rows/chunk
+    flat = kops.weighted_bincount_batched(ids[:2], vals[:2], 128)
+    np.testing.assert_array_equal(np.asarray(chunked[:2]), np.asarray(flat))
+    after = count("bincount_batch")
+    assert after.get("chunked", 0) - before.get("chunked", 0) == 1
+    assert after.get("flat", 0) - before.get("flat", 0) == 1
+
+    before = count("vector_plan")
+    assert kops.ell_vector_plan_ok(1, 1024, 8, 16)
+    assert not kops.ell_vector_plan_ok(64, 1 << 18, 64, 1024)
+    after = count("vector_plan")
+    assert after.get("ell", 0) - before.get("ell", 0) == 1
+    assert after.get("segment_sum", 0) - before.get("segment_sum", 0) == 1
 
 
 def test_trace_annotation_env_gate(monkeypatch):

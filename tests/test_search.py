@@ -360,3 +360,25 @@ def test_async_queue_search_matches_sync(seeded_rng):
     # flush events carry the normalized search params
     ev = [e for e in aq.flush_log if e.kind == "search_bm25"]
     assert ev and ev[0].terms == (1, 3) and ev[0].k == 3
+
+
+# ------------------------------------------- correctly rounded quotient --
+def test_div_rn_matches_ieee_division(seeded_rng):
+    """div_rn equals numpy's (IEEE) float32 division bit for bit, and
+    round_quotient pulls a quotient that is a few ulp off — as TPU's divide
+    returns for about a quarter of inputs — back to the IEEE one."""
+    from repro.search.engine import div_rn, round_quotient
+
+    n = 50_000
+    tf = seeded_rng.integers(0, 5000, n).astype(np.float32)
+    norm = (seeded_rng.random(n) * 3 + 0.1).astype(np.float32)
+    a = tf * np.float32(2.2)
+    b = tf + norm
+    want = a / b
+    np.testing.assert_array_equal(np.asarray(div_rn(a, b)), want)
+    pos = a > 0                   # an ulp error only exists off zero
+    bits = want[pos].view(np.int32)
+    for ulps in (-17, -3, -1, 1, 2, 5):
+        off = (bits + ulps).view(np.float32)
+        np.testing.assert_array_equal(
+            np.asarray(round_quotient(a[pos], b[pos], off)), want[pos])
