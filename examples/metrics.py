@@ -5,7 +5,8 @@ both the sync server and the async queue, and then shows what the
 observability layer captured (docs/observability.md):
 
 * the span tree of one query's whole lifecycle — submit, queue wait,
-  flush, pack build, compile/execute — read off ``query.trace``;
+  flush, pack build, execute with its readback and compile parts — read
+  off ``query.trace``;
 * the server's metrics registry rendered two ways: the JSON ``snapshot``
   (what BENCH uploads) and the Prometheus text exposition (what a
   scrape endpoint would serve);

@@ -81,6 +81,8 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.obs import plan_stage as _plan_stage
+from repro.obs.tracing import device_scope as _device_scope
+from repro.obs.tracing import readback as _readback
 
 from .grammar import (GrammarArrays, StaleGrammarError,
                       pow2_bucket as _pow2_bucket)
@@ -467,6 +469,7 @@ def _sharded_program(fn, mesh, in_ndims: Tuple[int, ...],
     return jax.jit(sm)
 
 
+@_device_scope("traversal")
 def _frontier_weights_impl(ep, ec, ef, valid, in_deg):
     """vmap of the masked frontier rounds; one shared while_loop.
 
@@ -505,6 +508,7 @@ def _frontier_weights_impl(ep, ec, ef, valid, in_deg):
 _frontier_weights_batched = jax.jit(_frontier_weights_impl)
 
 
+@_device_scope("traversal")
 def _leveled_weights_impl(ep, ec, ef, slices, R):
     """Shared static level schedule; each real edge touched exactly once
     (padded slots have freq 0)."""
@@ -604,6 +608,7 @@ def resolve_batch_method(gb: "GrammarBatch", method: str,
         f=gb.F_pad)
 
 
+@_device_scope("traversal")
 def _frontier_ell_impl(ell_src, ell_freq, in_deg):
     """Masked frontier rounds over the dense ELL plan: every round is ONE
     fused gather + row-sum (no scatter), with delta and the seen-counter
@@ -635,6 +640,7 @@ def _frontier_ell_impl(ell_src, ell_freq, in_deg):
 _frontier_weights_batched_ell = jax.jit(_frontier_ell_impl)
 
 
+@_device_scope("traversal")
 def _leveled_ell_impl(ell_src, ell_freq, level, num_levels):
     """Static level schedule over the dense ELL plan: level lv's round
     activates exactly the parents at that level, so each real edge
@@ -654,6 +660,7 @@ _leveled_weights_batched_ell = jax.jit(_leveled_ell_impl,
                                        static_argnames=("num_levels",))
 
 
+@_device_scope("traversal")
 def _frontier_fused_impl(ell_src, ell_freq, in_deg, num_levels):
     """The whole frontier loop in ONE dispatch (kernels.ops runs the jitted
     fori_loop form on TPU and CPU; the fused Pallas kernel is its
@@ -734,6 +741,7 @@ def batched_top_down_weights(gb: GrammarBatch,
     raise ValueError(f"unknown batched traversal method {method!r}")
 
 
+@_device_scope("traversal")
 def _per_file_frontier_impl(ep, ec, ef, valid, in_deg, root_seen,
                             fedge_child, fedge_file, fedge_freq, F):
     R = in_deg.shape[1]
@@ -771,6 +779,7 @@ _per_file_weights_batched = jax.jit(_per_file_frontier_impl,
                                     static_argnames=("F",))
 
 
+@_device_scope("traversal")
 def _per_file_leveled_impl(ep, ec, ef, fedge_child, fedge_file,
                            fedge_freq, slices, R, F):
     """Leveled per-file traversal: root edges are consumed by the per-file
@@ -795,6 +804,7 @@ _per_file_leveled_batched = jax.jit(_per_file_leveled_impl,
                                     static_argnames=("slices", "R", "F"))
 
 
+@_device_scope("traversal")
 def _per_file_frontier_ell_impl(ell_src, ell_freq, in_deg, root_seen,
                                 fedge_child, fedge_file, fedge_freq, F):
     """Per-file frontier rounds over the dense ELL plan with the VECTOR
@@ -833,6 +843,7 @@ _per_file_ell_batched = jax.jit(_per_file_frontier_ell_impl,
                                 static_argnames=("F",))
 
 
+@_device_scope("traversal")
 def _per_file_leveled_ell_impl(ell_src, ell_freq, level, fedge_child,
                                fedge_file, fedge_freq, num_levels, F):
     """Leveled per-file traversal over the dense ELL plan: level lv's
@@ -924,6 +935,7 @@ def batched_per_file_weights(gb: GrammarBatch,
 # Batched analytics (the six CompressDirect apps)                          #
 # ----------------------------------------------------------------------- #
 @functools.partial(jax.jit, static_argnames=("V",))
+@_device_scope("analytics")
 def _word_count_from_weights(w, tw_rule, tw_word, tw_cnt, V):
     vals = tw_cnt * jnp.take_along_axis(w, tw_rule, axis=1)
     return jax.vmap(
@@ -958,6 +970,7 @@ def batched_sort_words(gb: GrammarBatch, method: str = "frontier",
 
 
 @functools.partial(jax.jit, static_argnames=("V",))
+@_device_scope("analytics")
 def _term_vector_from_weights(Wf, tw_rule, tw_word, tw_cnt,
                               fword_file, fword_word, fword_cnt, V):
     def one(Wf, tr, twd, tc, ff, fw, fc):
@@ -1005,11 +1018,13 @@ def unbatch(gb: GrammarBatch, packed: jnp.ndarray,
     out = []
     for i, ga in enumerate(gb.real_gas):
         if kind == "word_count":
-            out.append(np.asarray(packed[i, : ga.vocab_size]))
+            part = packed[i, : ga.vocab_size]
         elif kind in ("term_vector", "inverted_index"):
-            out.append(np.asarray(packed[i, : ga.num_files, : ga.vocab_size]))
+            part = packed[i, : ga.num_files, : ga.vocab_size]
         else:
             raise ValueError(f"cannot unbatch kind {kind!r}")
+        with _readback():
+            out.append(np.asarray(part))
     return out
 
 
@@ -1017,6 +1032,7 @@ def unbatch(gb: GrammarBatch, packed: jnp.ndarray,
 # Batched sequence count (paper §IV-D across corpora)                      #
 # ----------------------------------------------------------------------- #
 @jax.jit
+@_device_scope("analytics")
 def _resolve_buffers_batched(is_lit, lit, src, idx, dep):
     R = is_lit.shape[1]
 
@@ -1045,6 +1061,7 @@ def _resolve_buffers_batched(is_lit, lit, src, idx, dep):
 
 
 @functools.partial(jax.jit, static_argnames=("l",))
+@_device_scope("analytics")
 def _count_windows_batched(head, tail, weights, st_kind, st_lit, st_src,
                            st_idx, st_symj, win_start, win_rule, win_valid,
                            l):
@@ -1146,9 +1163,10 @@ def batched_sequence_count(gb: GrammarBatch, l: int = 3,
     stok, seg, counts = _count_windows_batched(head, tail, weights,
                                                *stream, l)
 
-    stok_h = np.asarray(stok)
-    seg_h = np.asarray(seg)
-    counts_h = np.asarray(counts)
+    with _readback():
+        stok_h = np.asarray(stok)
+        seg_h = np.asarray(seg)
+        counts_h = np.asarray(counts)
     out: List[Tuple[np.ndarray, np.ndarray]] = []
     for i in range(gb.real):
         n_seg = int(seg_h[i, -1]) + 1
@@ -1175,9 +1193,9 @@ def run_batched(gb: GrammarBatch, kind: str, method: str = "frontier",
         return unbatch(gb, batched_word_count(gb, method=method,
                                               backend=backend), "word_count")
     if kind == "sort":
-        return [(np.asarray(o), np.asarray(c))
-                for (o, c) in batched_sort_words(gb, method=method,
-                                                 backend=backend)]
+        pairs = batched_sort_words(gb, method=method, backend=backend)
+        with _readback():
+            return [(np.asarray(o), np.asarray(c)) for o, c in pairs]
     if kind == "term_vector":
         return unbatch(gb, batched_term_vector(gb, method=method),
                        "term_vector")
@@ -1185,9 +1203,9 @@ def run_batched(gb: GrammarBatch, kind: str, method: str = "frontier",
         return unbatch(gb, batched_inverted_index(gb, method=method),
                        "inverted_index")
     if kind == "ranked_inverted_index":
-        return [(np.asarray(r), np.asarray(c))
-                for (r, c) in batched_ranked_inverted_index(gb,
-                                                            method=method)]
+        pairs = batched_ranked_inverted_index(gb, method=method)
+        with _readback():
+            return [(np.asarray(r), np.asarray(c)) for r, c in pairs]
     if kind == "sequence_count":
         return batched_sequence_count(gb, l=l, method=method)
     raise ValueError(f"unknown analytics kind {kind!r}; "

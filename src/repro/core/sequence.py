@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.tracing import readback as _readback
+
 from .grammar import GrammarArrays
 from .traversal import top_down_weights
 
@@ -336,11 +338,13 @@ def sequence_count(ga: GrammarArrays, l: int = 3, method: str = "frontier",
         return stok, seg, counts
 
     stok, seg, counts = count(head, tail, weights)
-    stok = np.asarray(stok)
-    counts = np.asarray(counts)
-    n_seg = int(np.asarray(seg)[-1]) + 1
+    with _readback():
+        stok = np.asarray(stok)
+        counts = np.asarray(counts)
+        n_seg = int(np.asarray(seg)[-1]) + 1
+        seg = np.asarray(seg)
     # representative token tuple of each segment = first row of the segment
-    first_idx = np.searchsorted(np.asarray(seg), np.arange(n_seg), "left")
+    first_idx = np.searchsorted(seg, np.arange(n_seg), "left")
     grams = stok[first_idx]
     cnts = counts[:n_seg]
     keep = cnts > 0

@@ -34,36 +34,15 @@ benchmarks/bench_batch.py runs the sweeps on its corpus set and records
 
 from __future__ import annotations
 
-import contextlib
 import os
 import subprocess
 import sys
 import time
 from typing import Any, Callable, Dict, Iterable, Optional, Sequence, Tuple
 
+from repro.obs.tracing import annotation
+
 from ._common import DEFAULT_BR, DEFAULT_FC, DEFAULT_WC, round_up_pow2
-
-# Opt-in profiler annotations: when this env var is set (non-empty, not
-# "0"), every timed candidate runs inside a named
-# ``jax.profiler.TraceAnnotation`` region, so a captured device trace
-# (``jax.profiler.trace``) attributes kernel time to the sweep candidate
-# that launched it.  Off by default — the annotation context has a small
-# per-call cost and tuning runs are usually not being profiled.
-ANNOTATE_ENV = "REPRO_PROFILE_ANNOTATIONS"
-
-
-def annotations_enabled() -> bool:
-    return os.environ.get(ANNOTATE_ENV, "") not in ("", "0")
-
-
-def trace_annotation(name: str) -> contextlib.AbstractContextManager:
-    """A context manager naming the enclosed device work in profiler
-    traces; a free ``nullcontext`` unless ``REPRO_PROFILE_ANNOTATIONS``
-    is set (jax import deferred so the off path stays import-free)."""
-    if not annotations_enabled():
-        return contextlib.nullcontext()
-    import jax
-    return jax.profiler.TraceAnnotation(name)
 
 # Candidate grids.  Small on purpose: each candidate is a fresh compile.
 BR_CANDIDATES = (128, 256, 512)
@@ -159,12 +138,12 @@ def tuned_blocks(kind: str, bucket: Sequence[int],
 def _time_call(fn: Callable[[], Any], repeat: int = 3,
                warmup: int = 1, name: str = "autotune") -> float:
     """Median seconds per call, steady-state (results block_until_ready).
-    ``name`` labels the timed region in profiler traces when
-    ``REPRO_PROFILE_ANNOTATIONS`` is on (see :func:`trace_annotation`)."""
+    ``name`` labels the timed region in profiler traces
+    (``repro.<name>``, :func:`repro.obs.tracing.annotation`)."""
     import jax
 
     def run() -> None:
-        with trace_annotation(name):
+        with annotation(name):
             jax.block_until_ready(fn())
 
     for _ in range(warmup):

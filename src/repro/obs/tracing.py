@@ -1,8 +1,9 @@
 """Span-based tracing of a query's lifecycle, plus the bounded event logs.
 
 A :class:`Span` is one named interval with attributes and children; the
-serving layer builds one tree per query — submit → (queue_wait) → flush →
-chunk → pack_build → plan:* → compile-or-execute — carried on
+serving layer builds one tree per query — submit → (queue_wait ⊃
+engine_wait) → flush → chunk → pack_build → execute (⊃ plan:*, readback,
+compile) — carried on
 ``Query.trace`` / ``FlushEvent.span`` and appended to the owning server's
 bounded ``trace_log``.  Trees may *share* subtrees: a flush that answers
 five queries is one flush span appearing under five query roots, which is
@@ -20,10 +21,27 @@ disjoint trees.
 When no span is active, ``plan_stage`` still feeds the global
 ``repro_plan_build_seconds`` histogram and costs one contextvar read
 otherwise — instrumentation must be safe to leave on everywhere.
+
+Profiler clock: every span opened and closed on one thread (:func:`span`,
+:func:`activate`, :func:`plan_stage`, :func:`readback`) also emits a
+``jax.profiler.TraceAnnotation`` named ``repro.<span name>``, so a
+captured profile holds the program's stages on the same clock as the
+device's ``XLA Ops``.  Outside a profiler session an annotation costs one
+TraceMe activity check; there is no switch.  Spans built after the fact
+(the query root, ``queue_wait``, which cross threads) stay host-tree-only.
+
+Device→host reads go through :func:`readback`, and JAX's own backend
+compile events (``/jax/core/compile/backend_compile_duration``, a compile
+or a load from the persistent cache) reach a process-wide listener that
+appends a ``compile`` child to the ambient span and reports the duration
+to the sink :func:`compiles_to` installed (the server executing the
+chunk) — so an execution's host self time is ``execute − readback −
+compile``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 import time
 from collections import deque
@@ -32,10 +50,18 @@ from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
+
 from .registry import global_registry
 
 __all__ = ["Span", "span", "activate", "current", "current_clock",
-           "plan_stage", "BoundedLog", "span_problems"]
+           "plan_stage", "readback", "readback_seconds", "annotation",
+           "compiles_to", "device_scope", "BoundedLog", "span_problems"]
+
+#: Prefix of the program's profiler annotations and device name scopes.
+PREFIX = "repro."
+#: JAX's duration event for one backend compile (or persistent-cache load).
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclass
@@ -84,6 +110,31 @@ _ACTIVE: ContextVar[Optional[Tuple[Span, Callable[[], float]]]] = \
     ContextVar("repro_obs_active_span", default=None)
 
 
+# where the compile listener reports durations: the server executing the
+# ambient chunk (:func:`compiles_to`)
+_COMPILE_SINK: ContextVar[Optional[Callable[[float], None]]] = \
+    ContextVar("repro_obs_compile_sink", default=None)
+
+
+def annotation(name: str) -> jax.profiler.TraceAnnotation:
+    """A profiler annotation ``repro.<name>`` on this thread: an event in
+    a captured profile's host plane, a TraceMe activity check otherwise."""
+    return jax.profiler.TraceAnnotation(PREFIX + name)
+
+
+def device_scope(name: str) -> Callable:
+    """Decorator: trace the function inside ``jax.named_scope("repro.<name>")``
+    so its operations carry a stable name in the compiled program's
+    metadata (a fresh scope per call; the traced code does not change)."""
+    def deco(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(PREFIX + name):
+                return fn(*args, **kwargs)
+        return scoped
+    return deco
+
+
 def current() -> Optional[Span]:
     """The ambient span, or None outside any instrumented scope."""
     top = _ACTIVE.get()
@@ -103,7 +154,8 @@ def activate(s: Span, clock: Callable[[], float]):
     query roots at submit time and finishes them when futures resolve)."""
     token = _ACTIVE.set((s, clock))
     try:
-        yield s
+        with annotation(s.name):
+            yield s
     finally:
         _ACTIVE.reset(token)
 
@@ -121,10 +173,72 @@ def span(name: str, clock: Optional[Callable[[], float]] = None,
         parent[0].children.append(s)
     token = _ACTIVE.set((s, clk))
     try:
-        yield s
+        with annotation(name):
+            yield s
     finally:
         _ACTIVE.reset(token)
         s.finish(clk())
+
+
+@contextmanager
+def readback():
+    """One device→host read (``np.asarray`` / ``int()`` of a device
+    array): a ``readback`` child of the ambient span, or only the
+    annotation outside any span.  It adds no synchronization: the read it
+    wraps is the host's wait on the device."""
+    if _ACTIVE.get() is None:
+        with annotation("readback"):
+            yield None
+    else:
+        with span("readback") as s:
+            yield s
+
+
+def readback_seconds(root: Span) -> float:
+    """Summed duration of the outermost ``readback`` spans under ``root``."""
+    total, stack = 0.0, [root]
+    while stack:
+        s = stack.pop()
+        if s.name == "readback" and s is not root:
+            total += s.duration
+        else:
+            stack.extend(s.children)
+    return total
+
+
+@contextmanager
+def compiles_to(sink: Callable[[float], None]):
+    """Report every backend compile on this thread inside the block to
+    ``sink(seconds)`` (the executing server's counter and stage)."""
+    token = _COMPILE_SINK.set(sink)
+    try:
+        yield
+    finally:
+        _COMPILE_SINK.reset(token)
+
+
+def _on_duration_event(event: str, duration: float, **kwargs) -> None:
+    """JAX monitoring listener; runs on the compiling thread as the
+    compile ends.  The ``compile`` child starts no earlier than its parent
+    or an earlier sibling: on a simulated clock the compile's real
+    duration would reach back past them."""
+    if event != COMPILE_EVENT:
+        return
+    top = _ACTIVE.get()
+    if top is not None:
+        parent, clk = top
+        now = clk()
+        t0 = max(now - duration, parent.t0,
+                 parent.children[-1].t0 if parent.children else parent.t0)
+        parent.children.append(Span(
+            "compile", t0, attrs={"fun_name": kwargs.get("fun_name")}
+        ).finish(now))
+    sink = _COMPILE_SINK.get()
+    if sink is not None:
+        sink(duration)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
 
 
 @contextmanager
@@ -142,9 +256,13 @@ def plan_stage(plan: str):
     if parent is not None:
         s = Span(f"plan:{plan}", t0)
         parent[0].children.append(s)
+    token = _ACTIVE.set((s, clk)) if s is not None else None
     try:
-        yield s
+        with annotation(f"plan:{plan}"):
+            yield s
     finally:
+        if token is not None:
+            _ACTIVE.reset(token)
         t1 = clk()
         if s is not None:
             s.finish(t1)

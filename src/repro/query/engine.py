@@ -46,6 +46,8 @@ from repro.core.batch import (GrammarBatch, _padded_sequence_plans,
                               _sharded_program, batched_top_down_weights)
 from repro.core.grammar import pow2_bucket
 from repro.core.sequence import _K_HEAD, _K_LIT, _K_TAIL
+from repro.obs.tracing import device_scope as _device_scope
+from repro.obs.tracing import readback as _readback
 from repro.search.engine import batch_search_stats
 from repro.search.index import base_method
 
@@ -66,6 +68,7 @@ QUERY_KINDS = ("filter_count", "agg_terms", "phrase_count")
 # ----------------------------------------------------------------------- #
 # The jitted programs (shard_map-compatible: batch-only leading axes)       #
 # ----------------------------------------------------------------------- #
+@_device_scope("query")
 def _filter_impl(tv, fvalid, terms, tvalid, thresh, structure=None):
     """bool [n, F] file mask.  ``terms [n, P]`` are pre-clipped leaf term
     ids, ``tvalid`` zeroes counts of out-of-range leaves, ``thresh`` the
@@ -90,6 +93,7 @@ def _filter_impl(tv, fvalid, terms, tvalid, thresh, structure=None):
 _filter = jax.jit(_filter_impl, static_argnames=("structure",))
 
 
+@_device_scope("query")
 def _agg_impl(tv, fvalid, terms, tvalid, op=None):
     """(per_file [n, F], total [n]) float32 aggregates of the term set.
 
@@ -118,6 +122,7 @@ def _agg_impl(tv, fvalid, terms, tvalid, op=None):
 _agg = jax.jit(_agg_impl, static_argnames=("op",))
 
 
+@_device_scope("query")
 def _phrase_impl(head, tail, weights, st_kind, st_lit, st_src, st_idx,
                  st_symj, win_start, win_rule, win_valid, phrase, l=None):
     """float32 [n] exact phrase counts from the pack's sequence plans.
@@ -187,7 +192,8 @@ def batched_filter(gb: GrammarBatch, predicate,
                                 static=(("structure", structure),))(*args)
     else:
         mask = _filter(*args, structure)
-    mask_h = np.asarray(mask)
+    with _readback():
+        mask_h = np.asarray(mask)
     return [np.flatnonzero(mask_h[i, : ga.num_files]).astype(np.int32)
             for i, ga in enumerate(gb.real_gas)]
 
@@ -210,8 +216,9 @@ def batched_agg(gb: GrammarBatch, terms: Sequence[int], op: str = "sum",
                                      (2, 1), static=(("op", op),))(*args)
     else:
         pf, total = _agg(*args, op)
-    pf_h = np.asarray(pf)
-    total_h = np.asarray(total)
+    with _readback():
+        pf_h = np.asarray(pf)
+        total_h = np.asarray(total)
     return [(pf_h[i, : ga.num_files], np.float32(total_h[i]))
             for i, ga in enumerate(gb.real_gas)]
 
@@ -232,7 +239,8 @@ def batched_phrase(gb: GrammarBatch, phrase: Sequence[int],
             static=(("l", l),))(head, tail, weights, *stream, ph)
     else:
         counts = _phrase(head, tail, weights, *stream, ph, l)
-    counts_h = np.asarray(counts)
+    with _readback():
+        counts_h = np.asarray(counts)
     return [np.float32(counts_h[i]) for i in range(gb.real)]
 
 
@@ -252,7 +260,8 @@ def _corpus_tv(source, method: str) -> Tuple[np.ndarray, object]:
     else:
         ga = source
         tv = _analytics.term_vector(ga, method=m)
-    return np.asarray(tv, np.float32), ga
+    with _readback():
+        return np.asarray(tv, np.float32), ga
 
 
 def filter_corpus(source, predicate,

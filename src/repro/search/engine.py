@@ -54,6 +54,8 @@ from repro.core.grammar import pow2_bucket
 from repro.distributed.shard_batch import shard_batch
 from repro.kernels import ops as kops
 from repro.obs import plan_stage as _plan_stage
+from repro.obs.tracing import device_scope as _device_scope
+from repro.obs.tracing import readback as _readback
 
 from .index import SearchIndex, base_method, build_search_index
 from .scoring import (DEFAULT_TOP_K, K1P1, SCHEMES, avg_doc_len, bm25_norm,
@@ -115,6 +117,7 @@ def div_rn(a, b):
     return round_quotient(a, b, a / b)
 
 
+@_device_scope("search")
 def _score_topk_impl(tv, terms, idf_q, norm, fvalid, k=None, scheme=None):
     """Score ``[n, F]`` docs against ``[n, Q]`` term slots and rank top-k.
 
@@ -169,8 +172,12 @@ def batch_search_stats(gb: GrammarBatch,
             tv = batched_term_vector(gb, method=m)
             # dl/df are integer-valued (exact in float32 in any reduce
             # order)
-            dl = np.asarray(jnp.sum(tv, axis=2), np.float32)    # [N, F_pad]
-            df = np.asarray(jnp.sum(tv > 0, axis=1)).astype(np.float32)
+            dl_dev = jnp.sum(tv, axis=2)                        # [N, F_pad]
+            with _readback():
+                dl = np.asarray(dl_dev, np.float32)
+            df_dev = jnp.sum(tv > 0, axis=1)
+            with _readback():
+                df = np.asarray(df_dev).astype(np.float32)
             nf = gb.num_files.astype(np.int64)
             norm = np.stack([
                 bm25_norm(dl[i], avg_doc_len(dl[i], int(nf[i])))
@@ -239,8 +246,9 @@ def batched_search(gb: GrammarBatch, terms: Sequence[int],
     else:
         vals, idx = _score_topk(st.tv, terms_dev, idf_dev, st.norm,
                                 st.fvalid, k_run, scheme)
-    vals_h = np.asarray(vals)
-    idx_h = np.asarray(idx)
+    with _readback():
+        vals_h = np.asarray(vals)
+        idx_h = np.asarray(idx)
     out: List[Tuple[np.ndarray, np.ndarray]] = []
     for i, ga in enumerate(gb.real_gas):
         k_eff = min(k, ga.num_files)
@@ -279,8 +287,9 @@ def search_index_topk(si: SearchIndex, terms: Sequence[int],
         tf_dev, jnp.asarray(t_clip)[None], jnp.asarray(idf_q),
         norm_dev, valid_dev, k_run, scheme)
     k_eff = min(k, si.n_docs)
-    return (np.asarray(idx)[0, :k_eff].astype(np.int32),
-            np.asarray(vals)[0, :k_eff])
+    with _readback():
+        return (np.asarray(idx)[0, :k_eff].astype(np.int32),
+                np.asarray(vals)[0, :k_eff])
 
 
 def search_corpus(source, terms: Sequence[int], k: int = DEFAULT_TOP_K,

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.core.analytics import term_vector
 from repro.core.grammar import GrammarArrays
+from repro.obs.tracing import readback as _readback
 
 from .scoring import avg_doc_len, bm25_norm, idf
 
@@ -84,7 +85,9 @@ def build_search_index(source, method: str = "frontier") -> SearchIndex:
         raise TypeError(f"cannot index {type(source).__name__}")
     fw = (source.per_file_weights(m)
           if hasattr(source, "per_file_weights") else None)
-    tf = np.asarray(term_vector(ga, method=m, file_weights=fw), np.float32)
+    tv = term_vector(ga, method=m, file_weights=fw)
+    with _readback():
+        tf = np.asarray(tv, np.float32)
     dl = tf.sum(axis=1, dtype=np.float32)
     df = (tf > 0).sum(axis=0).astype(np.float32)
     avgdl = avg_doc_len(dl)

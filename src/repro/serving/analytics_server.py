@@ -243,6 +243,8 @@ class ServerStats:
     * ``epoch_invalidations`` — packs dropped / corpora re-snapshotted
       because a registered store's epoch moved (append_files): each count
       is one "stale grammar could NOT be served" event;
+    * ``compiles`` — JAX backend compile events (a compile or a load from
+      the persistent cache) inside this server's chunks;
     * ``submitted`` / ``rejected`` / ``shed`` — async queue accounting
       (entered through submit, refused by max_pending, expired at flush);
     * ``max_queue_depth`` — pending-query high-water mark (a gauge).
@@ -277,6 +279,9 @@ class ServerStats:
         "epoch_invalidations": ("repro_server_epoch_invalidations_total",
                                 "stale packs/corpora dropped on an epoch "
                                 "bump (ingest appends)"),
+        "compiles": ("repro_server_compiles_total",
+                     "programs compiled or loaded from the persistent "
+                     "cache inside a chunk (JAX backend compile events)"),
         "submitted": ("repro_queue_submitted_total",
                       "queries entered through the async queue"),
         "rejected": ("repro_queue_rejected_total",
@@ -305,8 +310,9 @@ class ServerStats:
             "repro_server_method_fallbacks_total",
             "explicit ELL-family requests degraded to a segment_sum base",
             ("transition",)))
-        # submit-to-result decomposition: pack_build / compile / execute /
-        # queue_wait (docs/observability.md has the stage model)
+        # submit-to-result decomposition: queue_wait (engine_wait part of
+        # it) / pack_build / execute (readback and compile parts of it)
+        # (docs/observability.md has the stage model)
         self.stage_seconds = r.histogram(
             "repro_server_stage_seconds",
             "per-stage latency of query execution", ("stage",))
@@ -368,6 +374,11 @@ class ServerStats:
         if not vals:
             return default
         return max(vals)
+
+    def count_compile(self, seconds: float) -> None:
+        """One backend compile event inside a chunk (obs compile listener)."""
+        self._scalars["compiles"].inc()
+        self.stage_seconds.labels("compile").observe(seconds)
 
     def count_flush(self, reason: str) -> None:
         self.flushes[reason] = self.flushes.get(reason, 0) + 1
@@ -788,7 +799,7 @@ class AnalyticsServer:
                           attrs={"kind": kind, "n_corpora": len(chunk),
                                  "shards": shards})
               if tracing else nullcontext())
-        with cm as chunk_span:
+        with cm as chunk_span, _trace.compiles_to(self.stats.count_compile):
             if len(chunk) == 1 and shards == 1:
                 name = chunk[0]
                 if name in self._stores:
@@ -799,7 +810,7 @@ class AnalyticsServer:
                     with self._obs_stage("pack_build", tracing,
                                          path="store_memo"):
                         self._count_fallback(kind, ga=self._corpora[name])
-                    with self._obs_exec(kind, sig, tracing):
+                    with self._obs_exec(tracing):
                         out = {name: self._run_single(kind, name, l=l,
                                                       terms=terms, k=k,
                                                       predicate=predicate,
@@ -814,7 +825,7 @@ class AnalyticsServer:
                         gb = self._get_batch([name])
                         self._count_fallback(kind, gb=gb)
                     sig = gb.signature
-                    with self._obs_exec(kind, sig, tracing):
+                    with self._obs_exec(tracing):
                         vals = self._execute_batched(gb, kind, l, terms, k,
                                                      predicate=predicate,
                                                      agg=agg)
@@ -825,7 +836,7 @@ class AnalyticsServer:
                     gb = self._get_batch(list(chunk), shards=shards)
                     self._count_fallback(kind, gb=gb)
                 sig = gb.signature
-                with self._obs_exec(kind, sig, tracing):
+                with self._obs_exec(tracing):
                     vals = self._execute_batched(gb, kind, l, terms, k,
                                                  predicate=predicate,
                                                  agg=agg)
@@ -857,16 +868,18 @@ class AnalyticsServer:
             yield s
         self.stats.stage_seconds.labels(stage).observe(s.duration)
 
-    def _obs_exec(self, kind: str, sig: Tuple, tracing: bool):
-        """The device-execution stage.  Named ``compile`` on the first
-        execution of a (kind, signature) pair — that call pays jit
-        compilation, the same first-call the latency EWMA skips
-        (``observe_latency``) — and ``execute`` on every later one."""
-        if not tracing:
-            return nullcontext()
-        first = (kind, sig) not in self.stats.latency_obs
-        return self._obs_stage("compile" if first else "execute", True,
-                               first_call=first)
+    @contextmanager
+    def _obs_exec(self, tracing: bool):
+        """The ``execute`` stage: host tracing and dispatch, the host's
+        waits on the device, device→host copies.  Its ``readback``
+        children (the waits and copies) are summed into the ``readback``
+        stage; JAX's compile events land in the ``compile`` stage through
+        the chunk's :func:`~repro.obs.tracing.compiles_to` sink."""
+        with self._obs_stage("execute", tracing) as s:
+            yield s
+        if s is not None:
+            self.stats.stage_seconds.labels("readback").observe(
+                _trace.readback_seconds(s))
 
     # ---------------------------------------------------------- internals --
     def _get_batch(self, names: Sequence[str],
@@ -929,20 +942,26 @@ class AnalyticsServer:
                           "ranked_inverted_index"):
                 wf = store.per_file_weights(m)
         if kind == "word_count":
-            return np.asarray(_analytics.word_count(ga, method=m, weights=w))
+            x = _analytics.word_count(ga, method=m, weights=w)
+            with _trace.readback():
+                return np.asarray(x)
         if kind == "sort":
             o, c = _analytics.sort_words(ga, method=m, weights=w)
-            return (np.asarray(o), np.asarray(c))
+            with _trace.readback():
+                return (np.asarray(o), np.asarray(c))
         if kind == "term_vector":
-            return np.asarray(_analytics.term_vector(ga, method=m,
-                                                     file_weights=wf))
+            x = _analytics.term_vector(ga, method=m, file_weights=wf)
+            with _trace.readback():
+                return np.asarray(x)
         if kind == "inverted_index":
-            return np.asarray(_analytics.inverted_index(ga, method=m,
-                                                        file_weights=wf))
+            x = _analytics.inverted_index(ga, method=m, file_weights=wf)
+            with _trace.readback():
+                return np.asarray(x)
         if kind == "ranked_inverted_index":
             r, c = _analytics.ranked_inverted_index(ga, method=m,
                                                     file_weights=wf)
-            return (np.asarray(r), np.asarray(c))
+            with _trace.readback():
+                return (np.asarray(r), np.asarray(c))
         if kind == "sequence_count":
             return _analytics.sequence_count(ga, l=l, method=m, weights=w)
         raise ValueError(f"unknown analytics kind {kind!r}")

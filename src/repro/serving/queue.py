@@ -404,14 +404,22 @@ class AsyncAnalyticsServer:
         # starving the rest of the group and killing the serve loop
         claimed = [p for p in g.items
                    if p.future.set_running_or_notify_cancel()]
-        if tracing:
-            # every claimed query waited submit -> flush, shed or not
+        if tracing and claimed:
+            # every claimed query waited submit -> flush, shed or not; the
+            # part after the policy made its group due (or after its
+            # submit, if later) it waited for the engine to be free
             wait_hist = self.stats.stage_seconds.labels("queue_wait")
+            engine_hist = self.stats.stage_seconds.labels("engine_wait")
+            due = min(self._next_trigger(g), now)
             for p in claimed:
+                ready = max(due, p.submitted_at)
                 wait_hist.observe(now - p.submitted_at)
+                engine_hist.observe(now - ready)
                 if p.span is not None:
-                    p.span.children.append(_trace.Span(
-                        "queue_wait", p.submitted_at).finish(now))
+                    w = _trace.Span("queue_wait", p.submitted_at).finish(now)
+                    w.children.append(
+                        _trace.Span("engine_wait", ready).finish(now))
+                    p.span.children.append(w)
         # shed the expired: a deadline already in the past cannot be met by
         # any execution, so the engine slot goes to queries that can still
         # use it.  Fail the futures before the engine call — their callers
@@ -522,7 +530,9 @@ class AsyncAnalyticsServer:
             delay = self.poll_interval
             if nxt is not None:
                 delay = min(delay, max(nxt - now, 0.0))
-            self._wake.wait(delay)
+            # labels a device idle gap "serve thread waiting for requests"
+            with _trace.annotation("queue.idle"):
+                self._wake.wait(delay)
             self._wake.clear()
 
     def _kick(self) -> None:

@@ -13,15 +13,22 @@ The acceptance bar this suite holds (docs/observability.md):
 * every query through the sync server or the async queue produces a
   complete span tree — no stage gaps (``span_problems`` is the checker);
 * the ``enabled`` flag's asymmetry: counters/gauges always record,
-  histograms and span construction go dark when disabled.
+  histograms and span construction go dark when disabled;
+* the spans are on the profiler's clock (``repro.*`` annotations in a
+  captured trace, nested as the tree is), and ``compile`` spans and
+  counts are JAX's own backend compile events.
 """
 
+import contextlib
+import glob
 import json
 import math
+import os
 import re
 import threading
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -348,17 +355,60 @@ def test_concurrent_submit_exact_accounting():
         assert sum(child._counts) == child.count
 
 
+@contextlib.contextmanager
+def _backend_compiles():
+    """JAX's backend compile events on this thread, seen by a listener of
+    the test's own (not the program's)."""
+    seen = []
+    me = threading.get_ident()
+
+    def listen(event, duration, **_):
+        if (event == "/jax/core/compile/backend_compile_duration"
+                and threading.get_ident() == me):
+            seen.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield seen
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+
+
+def _host_events(tmp_path, fn):
+    """``repro.*`` host events ``(name, start_ns, end_ns)`` of a profiler
+    trace captured on the CPU around ``fn()``."""
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
 # ------------------------------------------------------------ span trees --
 def test_sync_span_tree_complete():
     eng = _build_engine(seed=99)
     qs = [Query(f"c{i}", "word_count") for i in range(2)]
-    eng.run(qs)                                  # cold: pays the compile
+    jax.clear_caches()                           # cold whatever ran before
+    with _backend_compiles() as seen:
+        eng.run(qs)                              # cold: pays the compiles
+    assert seen
     for q in qs:
         root = q.trace
         assert root is not None and root.attrs["path"] == "sync"
         assert span_problems(
-            root, require=("run_group", "chunk", "pack_build")) == []
-        assert root.find("compile"), "first call must trace as compile"
+            root, require=("run_group", "chunk", "pack_build", "execute",
+                           "compile")) == []
+        # one compile span per backend compile event, none guessed
+        assert len(root.find("compile")) == len(seen)
     # the shared chunk subtree IS the batching: both roots hold it
     assert qs[0].trace.find("chunk")[0] is qs[1].trace.find("chunk")[0]
 
@@ -371,6 +421,87 @@ def test_sync_span_tree_complete():
     chunk = root.find("chunk")[0]
     assert chunk.attrs["cache_hit"] is True
     assert len(eng.trace_log) == 4               # every root was logged
+
+
+def test_compile_events_counted():
+    """A cold call on a fresh shape adds as many ``compile`` spans, stage
+    observations and ``stats.compiles`` as JAX had backend compile events;
+    a warm call adds none."""
+    eng = _build_engine(n_corpora=2, seed=123)
+    jax.clear_caches()
+    with _backend_compiles() as seen:
+        eng.run([Query(f"c{i}", "sort") for i in range(2)])
+    assert seen and eng.stats.compiles == len(seen)
+    stage = eng.stats.stage_seconds.labels("compile")
+    assert stage.count == len(seen)
+    assert stage.sum == pytest.approx(sum(seen))
+    # the chunk's tree says which programs compiled
+    compiled = eng.trace_log[-1].find("chunk")[0].find("compile")
+    assert len(compiled) == len(seen)
+    assert all(s.attrs["fun_name"] for s in compiled)
+
+    with _backend_compiles() as seen_warm:
+        warm = [Query(f"c{i}", "sort") for i in range(2)]
+        eng.run(warm)
+    assert seen_warm == [] and eng.stats.compiles == len(seen)
+    assert not warm[0].trace.find("compile")
+    assert stage.count == len(seen)
+
+
+def test_readback_stage_inside_execute():
+    """Every execution observes ``execute`` and ``readback``; the
+    device→host reads are ``readback`` spans inside ``execute``."""
+    eng = _build_engine(n_corpora=2, seed=31)
+    for kind in ("word_count", "sort", "term_vector"):
+        q = Query("c0", kind)
+        eng.run([q, Query("c1", kind)])
+        ex = q.trace.find("execute")[0]
+        reads = ex.find("readback")
+        assert reads and all(r.t0 >= ex.t0 and r.t1 <= ex.t1 for r in reads)
+    h = eng.stats.stage_seconds
+    assert h.labels("readback").count == h.labels("execute").count == 3
+    assert 0 < h.labels("readback").sum <= h.labels("execute").sum
+
+
+def test_spans_on_the_profiler_clock(tmp_path):
+    """A profiler trace captured on the CPU around one sync ``run()``
+    holds the program's spans as ``repro.*`` host events, nested as the
+    span tree is."""
+    eng = _build_engine(n_corpora=2, seed=47)
+    qs = [Query(f"c{i}", "word_count") for i in range(2)]
+    eng.run(qs)                                  # compile outside the trace
+    events = _host_events(tmp_path, lambda: eng.run(qs))
+    by_name = {}
+    for name, t0, t1 in events:
+        by_name.setdefault(name, []).append((t0, t1))
+    for name in ("repro.run_group", "repro.chunk", "repro.pack_build",
+                 "repro.execute", "repro.readback"):
+        assert name in by_name, sorted(by_name)
+
+    def inside(child, parent):
+        return all(any(p0 <= c0 and c1 <= p1 for p0, p1 in by_name[parent])
+                   for c0, c1 in by_name[child])
+
+    assert inside("repro.chunk", "repro.run_group")
+    assert inside("repro.pack_build", "repro.chunk")
+    assert inside("repro.execute", "repro.chunk")
+    assert inside("repro.readback", "repro.execute")
+    # the same counts as the span tree of the traced call
+    tree = qs[0].trace
+    assert len(by_name["repro.readback"]) == len(tree.find("readback"))
+    assert len(by_name["repro.chunk"]) == len(tree.find("chunk"))
+
+
+def test_serve_loop_idle_annotation(tmp_path):
+    """The serve thread's wait for work is a ``repro.queue.idle`` event."""
+    eng = _build_engine(n_corpora=1, seed=53)
+
+    def serve_idle():
+        with AsyncAnalyticsServer(eng, poll_interval=0.001):
+            time.sleep(0.05)
+
+    names = {name for name, _, _ in _host_events(tmp_path, serve_idle)}
+    assert "repro.queue.idle" in names
 
 
 def test_async_span_tree_simclock():
@@ -549,17 +680,14 @@ def test_vocabulary_gate_dispatch_counters(monkeypatch):
     assert after.get("segment_sum", 0) - before.get("segment_sum", 0) == 1
 
 
-def test_trace_annotation_env_gate(monkeypatch):
-    from contextlib import nullcontext
-
+def test_trace_annotation_env_gate(tmp_path):
+    """No switch gates the autotuner's annotations: a timed candidate is a
+    ``repro.autotune:<candidate>`` event in a CPU trace."""
     from repro.kernels import autotune
 
-    monkeypatch.delenv(autotune.ANNOTATE_ENV, raising=False)
-    assert not autotune.annotations_enabled()
-    assert isinstance(autotune.trace_annotation("x"), nullcontext)
-    monkeypatch.setenv(autotune.ANNOTATE_ENV, "0")
-    assert not autotune.annotations_enabled()
-    monkeypatch.setenv(autotune.ANNOTATE_ENV, "1")
-    assert autotune.annotations_enabled()
-    with autotune.trace_annotation("obs-test"):   # real annotation works
-        pass
+    x = jnp.arange(8.0)
+    names = {name for name, _, _ in _host_events(
+        tmp_path, lambda: autotune._time_call(lambda: x * 2, repeat=1,
+                                              warmup=0,
+                                              name="autotune:obs-test"))}
+    assert "repro.autotune:obs-test" in names

@@ -195,6 +195,46 @@ def test_idle_flush_after_timeout():
     assert f2.done()
 
 
+def test_engine_wait_splits_queue_wait():
+    """A stub engine holds the first flush for ``hold`` s of simulated
+    time.  The second group was due 2 ms after the first flush fired, so
+    its ``engine_wait`` is ``hold`` less those 2 ms, and its ``queue_wait``
+    is its batching wait (``idle_timeout``) plus ``engine_wait``."""
+    eng = _build_engine(n_corpora=2, seed=21)
+    clk = SimClock()
+    hold = 2.0
+
+    def run_group(kind, names, **_):            # the engine, busy hold s
+        clk.t += hold
+        return {n: kind for n in names}
+
+    eng.run_group = run_group
+    aq = AsyncAnalyticsServer(eng, idle_timeout=0.005, max_wait=0.05,
+                              clock=clk)
+    qa, qb = Query("c0", "word_count"), Query("c1", "sort")
+    fa = aq.submit(qa)                          # group A at 0, due 0.005
+    clk.t = 0.002
+    fb = aq.submit(qb)                          # group B, due 0.007
+    clk.t = 0.005
+    aq.poll()                                   # A flushes, engine busy
+    assert fa.result(timeout=0) == "word_count" and not fb.done()
+    aq.poll()                                   # at 2.005 B's flush claims
+    assert fb.result(timeout=0) == "sort"
+
+    wait_b = qb.trace.find("queue_wait")[0]
+    (engine_b,) = wait_b.children
+    assert engine_b.name == "engine_wait"
+    assert engine_b.duration == pytest.approx(hold - 0.002)
+    assert wait_b.duration == pytest.approx(0.005 + engine_b.duration)
+    assert qa.trace.find("engine_wait")[0].duration == pytest.approx(0.0)
+    h = eng.stats.stage_seconds
+    assert h.labels("engine_wait").count == 2
+    assert h.labels("engine_wait").sum == pytest.approx(hold - 0.002)
+    assert h.labels("queue_wait").sum == pytest.approx(
+        0.005 + 0.005 + h.labels("engine_wait").sum)
+    aq.close()
+
+
 def test_sustained_stream_bounded_by_max_wait():
     """A same-corpus stream resets idleness on every arrival and never
     fills a pack; the oldest query must still flush within max_wait."""
