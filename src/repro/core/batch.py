@@ -29,21 +29,40 @@ Dimensions are bucketed (rounded up to powers of two) so batches of similar
 size hit the same compiled program — the dispatch layer
 (serving/analytics_server.py) groups queries by this signature.
 
-DESIGN — the ELL edge plan (methods ``frontier_ell`` / ``leveled_ell``):
-:meth:`GrammarBatch.ell_plan` converts each corpus's COO in-edges to a
-dense ``src/freq [N, R_pad, K]`` layout (row r = rule r's parents, K = max
-in-degree across the batch bucketed to a power of two, padding src=0 /
-freq=0).  Because the row index IS the destination rule, one propagation
-round needs no scatter: ``kernels.ops.ell_propagate_batched`` fuses the
-gather, mask-gate, multiply and row-sum — and emits the ``seen`` frontier
-bookkeeping — in a single launch (two segment_sum scatters per round on
-the COO path).  The plan is built lazily and memoized per batch; method
-``auto`` asks ``kernels.ops.ell_batched_use_ref`` (occupancy over edge
-count, plan width K, batch width N) whether the dense plan pays off.  The
-leveled variant replays the same plan once per level with the mask
-``level[parent] == lv`` — each real edge still contributes exactly once,
-at its parent's level.  Per-file traversals keep the segment_sum path
-(their payload is a [R, F] vector per rule; the ELL kernels are scalar).
+DESIGN — the ELL edge plan (methods ``frontier_ell`` / ``leveled_ell`` /
+``frontier_fused``): :meth:`GrammarBatch.ell_plan` converts each corpus's
+COO in-edges to a dense ``src/freq [N, R_pad, K]`` layout (row r = rule
+r's parents, K = max in-degree across the batch bucketed to a power of
+two, padding src=0 / freq=0).  Because the row index IS the destination
+rule, one propagation round needs no scatter: ``kernels.ops.
+ell_propagate_batched`` fuses the gather, mask-gate, multiply and row-sum —
+and emits the ``seen`` frontier bookkeeping — in a single launch (two
+segment_sum scatters per round on the COO path).  Plans are built lazily
+and memoized per batch; method ``auto`` asks ``kernels.ops.
+ell_batched_use_ref`` (occupancy over edge count, the plan's slot count,
+batch width N) whether the plan pays off.  The leveled variants replay the
+same plan once per level with the mask ``level[parent] == lv`` — each real
+edge still contributes exactly once, at its parent's level.  Per-file
+traversals run the same plan with an ``[R, F]`` payload per rule.
+
+DESIGN — hub rows are split (:meth:`GrammarBatch.ell_split_plan`, what the
+ELL engines run).  In-degrees are skewed: a few hub rules are used by
+hundreds of rules, most by two or three, and a dense plan gathers K_max
+slots for every row in every round.  The split plan keeps a main plan
+``[N, R_pad, K0]`` and moves each hub's in-edges (in-degree > K0) into
+chunks of K1 on overflow rows ``[N, O_pad, K1]``; the hub's own row holds
+one pointer slot per chunk (src ``R_pad + o``, freq 1).  A round first
+sums the overflow rows over ``weight * active`` (their delta and seen
+partials), then the main rows over ``[weight * active ; overflow delta]``
+and ``[active ; overflow seen]`` — still scatter-free, and no extra round,
+where turning chunks into virtual rules would add rounds to the critical
+path.  ``(K0, K1, O_pad)`` are powers of two chosen by
+:func:`ell_split_shape` from the pack's in-degree histograms alone, to
+minimise the slot count ``N * (R_pad * K0 + O_pad * K1)``; O_pad is the
+largest corpus's need, so every shard of a sharded pack runs one program.
+When no row exceeds the chosen K0 the split plan IS the dense plan (same
+arrays, same program).  Counts are integer-valued float32 below 2**24, so
+the split sums equal the dense ones bit for bit.
 
 DESIGN — device-sharded batches (:meth:`GrammarBatch.shard`): the corpus
 axis N is embarrassingly parallel (every traversal above is a vmap over
@@ -80,12 +99,12 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.obs import global_registry
 from repro.obs import plan_stage as _plan_stage
 from repro.obs.tracing import device_scope as _device_scope
 from repro.obs.tracing import readback as _readback
 
-from .grammar import (GrammarArrays, StaleGrammarError,
-                      pow2_bucket as _pow2_bucket)
+from .grammar import GrammarArrays, StaleGrammarError, pow2_bucket
 from . import sequence as _sequence
 from .sequence import _K_HEAD, _K_LIT, _K_TAIL
 
@@ -102,6 +121,36 @@ def _round_up_pow2(x: int, minimum: int = 8) -> int:
     if x <= minimum:
         return minimum
     return 1 << (int(x) - 1).bit_length()
+
+
+def ell_split_shape(hists: Sequence[np.ndarray],
+                    rows: int) -> Tuple[int, int, int]:
+    """``(K0, K1, O_pad)`` of the split ELL plan (module DESIGN note).
+
+    ``hists[i][d]`` is the number of corpus i's rules with in-degree d and
+    ``rows`` the pack's padded rule count.  Every power-of-two K0 below the
+    dense width, and every power-of-two K1 wide enough that the widest hub
+    needs at most K0 overflow rows, is priced at ``rows * K0 + O_pad * K1``
+    slots per corpus (O_pad: the largest corpus's overflow rows, bucketed);
+    the cheapest wins, the dense plan ``(K, K, 0)`` on ties.  A pure
+    function of the histograms and ``rows``.
+    """
+    kmax = max((len(h) - 1 for h in hists), default=0)
+    dense = pow2_bucket(kmax)
+    degs = [(np.flatnonzero(h), h[np.flatnonzero(h)]) for h in hists]
+    best = (rows * dense, dense, dense, 0)
+    k0 = 1
+    while k0 < dense:
+        k1 = pow2_bucket(-(-kmax // k0))
+        while k1 <= dense:
+            need = max(int((c * -(-d // k1))[d > k0].sum()) for d, c in degs)
+            o_pad = pow2_bucket(need) if need else 0
+            cost = rows * k0 + o_pad * k1
+            if cost < best[0]:
+                best = (cost, k0, k1, o_pad)
+            k1 *= 2
+        k0 *= 2
+    return best[1:]
 
 
 def _pad_stack(arrs: Sequence[np.ndarray], width: int, fill=0,
@@ -297,7 +346,7 @@ class GrammarBatch:
         if ("ell_width",) not in self._plan_cache:
             kmax = max((int(ga.in_deg.max(initial=0)) for ga in self.gas),
                        default=0)
-            self._plan_cache[("ell_width",)] = _pow2_bucket(kmax)
+            self._plan_cache[("ell_width",)] = pow2_bucket(kmax)
         return self._plan_cache[("ell_width",)]
 
     def ell_plan(self) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, int]:
@@ -307,8 +356,9 @@ class GrammarBatch:
         per-corpus :meth:`GrammarArrays.in_edges_ell_dense` plans stacked to
         a shared K, ``level[i, r]`` is corpus i's rule level (-1 on padded
         rule slots — never active in the leveled replay), and num_levels the
-        shared (max) level count.  Built lazily: packs that never run an ELL
-        method never pay the dense layout.
+        shared (max) level count.  Built lazily: the engines run
+        :meth:`ell_split_plan`, which builds this plan only when it splits
+        no row.
         """
         key = ("ell",)
         if key not in self._plan_cache:
@@ -316,16 +366,84 @@ class GrammarBatch:
                 K = self.ell_plan_width()
                 src = np.zeros((self.n, self.R_pad, K), np.int32)
                 freq = np.zeros((self.n, self.R_pad, K), np.float32)
-                level = np.full((self.n, self.R_pad), -1, np.int32)
                 for i, ga in enumerate(self.gas):
                     s, f = ga.in_edges_ell_dense(k=K)
                     src[i, : ga.num_rules] = s
                     freq[i, : ga.num_rules] = f
-                    level[i, : ga.num_rules] = ga.level
                 self._plan_cache[key] = (
                     self._place(src), self._place(freq),
-                    self._place(level),
-                    max(ga.num_levels for ga in self.gas))
+                    *self._levels())
+        return self._plan_cache[key]
+
+    def _levels(self) -> Tuple[jnp.ndarray, int]:
+        """Per-rule levels ``[N, R_pad]`` (-1 on padded rule slots) and the
+        shared (max) level count, for the ELL plans."""
+        level = np.full((self.n, self.R_pad), -1, np.int32)
+        for i, ga in enumerate(self.gas):
+            level[i, : ga.num_rules] = ga.level
+        return (self._place(level),
+                max(ga.num_levels for ga in self.gas))
+
+    def ell_split_shape(self) -> Tuple[int, int, int]:
+        """``(K0, K1, O_pad)`` of the split plan (:func:`ell_split_shape`
+        over the pack's in-degree histograms) — host-only and memoized, so
+        the dispatch gates read it on every call without building a plan.
+        ``O_pad == 0``: no row is split and K0 is the dense width."""
+        if ("ell_split_shape",) not in self._plan_cache:
+            self._plan_cache[("ell_split_shape",)] = ell_split_shape(
+                [np.bincount(ga.in_deg, minlength=1) for ga in self.gas],
+                self.R_pad)
+        return self._plan_cache[("ell_split_shape",)]
+
+    def ell_plan_slots(self) -> int:
+        """Slots of the plan the ELL engines run: ``N * (R_pad * K0 +
+        O_pad * K1)`` (the dense ``N * R_pad * K`` when nothing splits)."""
+        k0, k1, o_pad = self.ell_split_shape()
+        return self.n * (self.R_pad * k0 + o_pad * k1)
+
+    def ell_split_plan(self) -> Tuple[jnp.ndarray, jnp.ndarray, tuple,
+                                      jnp.ndarray, int]:
+        """The plan the ELL engines run (module DESIGN note), memoized.
+
+        Returns ``(src, freq, ovf, level, num_levels)``: the main plan
+        ``[N, R_pad, K0]``, ``ovf`` the trailing arguments the ELL programs
+        take — ``()`` when no row is split (then src/freq/level are
+        :meth:`ell_plan`'s own arrays), else ``((ovf_src, ovf_freq),)``
+        with the overflow plan ``[N, O_pad, K1]`` — and the levels as in
+        :meth:`ell_plan`.  Counted on ``repro_kernel_dispatch_total
+        {decision="ell_split"}``, and its slots per real edge set the gauge
+        ``repro_ell_plan_slots_per_edge``.
+        """
+        from repro.kernels import ops as kops
+
+        key = ("ell_split",)
+        if key in self._plan_cache:
+            return self._plan_cache[key]
+        k0, k1, o_pad = self.ell_split_shape()
+        if not o_pad:
+            src, freq, level, num_levels = self.ell_plan()
+            ovf = ()
+        else:
+            with _plan_stage("ell"):
+                src = np.zeros((self.n, self.R_pad, k0), np.int32)
+                freq = np.zeros((self.n, self.R_pad, k0), np.float32)
+                osrc = np.zeros((self.n, o_pad, k1), np.int32)
+                ofreq = np.zeros((self.n, o_pad, k1), np.float32)
+                for i, ga in enumerate(self.gas):
+                    s, f, os_, of = ga.in_edges_ell_split(k0, k1, self.R_pad)
+                    src[i, : ga.num_rules] = s
+                    freq[i, : ga.num_rules] = f
+                    osrc[i, : len(os_)] = os_
+                    ofreq[i, : len(of)] = of
+                src, freq = self._place(src), self._place(freq)
+                ovf = ((self._place(osrc), self._place(ofreq)),)
+                level, num_levels = self._levels()
+        kops.count_ell_split(bool(o_pad))
+        global_registry().gauge(
+            "repro_ell_plan_slots_per_edge",
+            "slots of the last ELL plan built per real in-edge").set(
+                self.ell_plan_slots() / max(self.total_edges, 1))
+        self._plan_cache[key] = (src, freq, ovf, level, num_levels)
         return self._plan_cache[key]
 
     # ------------------------------------------------------------ build --
@@ -527,7 +645,7 @@ _leveled_weights_batched = jax.jit(_leveled_weights_impl,
                                    static_argnames=("slices", "R"))
 
 
-# Methods that run on the dense ELL plan, and the segment_sum bases an
+# Methods that run on the ELL plan, and the segment_sum bases an
 # ineligible request degrades to.  ``resolve_traversal_method`` is the ONE
 # place the gates live: the engines dispatch on its answer and the serving
 # layer compares requested-vs-resolved to count downgrades
@@ -543,12 +661,15 @@ PER_FILE_KINDS = ("term_vector", "inverted_index", "ranked_inverted_index")
 
 def resolve_traversal_method(method: str, *, n: int, rows: int, k: int,
                              edges: int, shards: int = 1,
-                             per_file: bool = False, f: int = 1) -> str:
+                             per_file: bool = False, f: int = 1,
+                             slots: Optional[int] = None) -> str:
     """Resolve a requested traversal method against the pack's shape gates.
 
-    Pure over dimensions (n/rows/k are the pack's N, R_pad and ELL plan
-    width; ``f`` is F_pad for per-file traversals) so the serving layer can
-    predict the engine's routing without building a plan.  Rules:
+    Pure over dimensions (n/rows are the pack's N and R_pad, k the widest
+    row of the ELL plan that would run and ``slots`` its slot count —
+    default the dense ``n * rows * k``; ``f`` is F_pad for per-file
+    traversals) so the serving layer can predict the engine's routing
+    without building a plan.  Rules:
 
     * ``auto`` — occupancy dispatch (kernels.ops.ell_batched_use_ref, per
       shard), then the fused path when the scalar state fits VMEM;
@@ -562,10 +683,11 @@ def resolve_traversal_method(method: str, *, n: int, rows: int, k: int,
     from repro.kernels import ops as kops
 
     if method == "auto":
-        if kops.ell_batched_use_ref(edges, n, rows, k, shards=shards):
+        if kops.ell_batched_use_ref(edges, n, rows, k, shards=shards,
+                                    slots=slots):
             return "frontier"
         if per_file:
-            if not kops.ell_vector_plan_ok(n, rows, k, f):
+            if not kops.ell_vector_plan_ok(n, rows, k, f, slots=slots):
                 return "frontier"
             return "frontier_ell"
         if kops.ell_fused_use_kernel(rows):
@@ -573,15 +695,15 @@ def resolve_traversal_method(method: str, *, n: int, rows: int, k: int,
         return "frontier_ell"
     if method in ELL_METHODS:
         # safety valves even when ELL is requested explicitly: a skewed
-        # grammar (hub rule with huge in-degree) or a huge sparse one
-        # (many rules x a moderate hub's K) would make the dense plan
-        # O(N * R_pad * K) memory — fall back to the segment_sum base
-        # (identical results).
+        # grammar (a row wider than any realistic bucket) or a huge one
+        # (a plan of more slots than the budget) falls back to the
+        # segment_sum base (identical results).
         if (k > kops.ELL_BATCH_MAX_WIDTH
-                or n * rows * k > kops.ELL_PLAN_MAX_ENTRIES):
+                or (n * rows * k if slots is None else slots)
+                > kops.ELL_PLAN_MAX_ENTRIES):
             return SEGMENT_SUM_BASES[method]
         if per_file:
-            if not kops.ell_vector_plan_ok(n, rows, k, f):
+            if not kops.ell_vector_plan_ok(n, rows, k, f, slots=slots):
                 return SEGMENT_SUM_BASES[method]
             if method == "frontier_fused":
                 return "frontier_ell"
@@ -599,20 +721,24 @@ def is_segment_sum_fallback(requested: str, resolved: str) -> bool:
 
 def resolve_batch_method(gb: "GrammarBatch", method: str,
                          per_file: bool = False) -> str:
-    """`resolve_traversal_method` with the dims read off a built pack."""
+    """`resolve_traversal_method` with the dims read off a built pack: the
+    gates judge the split plan the ELL engines would run."""
     if method != "auto" and method not in ELL_METHODS:
         return method
+    k0, k1, _ = gb.ell_split_shape()
     return resolve_traversal_method(
-        method, n=gb.n, rows=gb.R_pad, k=gb.ell_plan_width(),
+        method, n=gb.n, rows=gb.R_pad, k=max(k0, k1),
         edges=gb.total_edges, shards=gb.shards, per_file=per_file,
-        f=gb.F_pad)
+        f=gb.F_pad, slots=gb.ell_plan_slots())
 
 
 @_device_scope("traversal")
-def _frontier_ell_impl(ell_src, ell_freq, in_deg):
-    """Masked frontier rounds over the dense ELL plan: every round is ONE
-    fused gather + row-sum (no scatter), with delta and the seen-counter
-    emitted by the same kernels.ops.ell_propagate_batched call."""
+def _frontier_ell_impl(ell_src, ell_freq, in_deg, ovf=()):
+    """Masked frontier rounds over the ELL plan: every round is ONE fused
+    gather + row-sum (no scatter), with delta and the seen-counter emitted
+    by the same kernels.ops.ell_propagate_batched call.  ``ovf`` is the
+    overflow plan of a split plan (module DESIGN note), empty for a dense
+    one — then the program is the dense plan's."""
     from repro.kernels import ops as kops
 
     N, R = in_deg.shape
@@ -624,7 +750,7 @@ def _frontier_ell_impl(ell_src, ell_freq, in_deg):
     def body(state):
         weight, cur_in, mask, ever = state
         delta, seen = kops.ell_propagate_batched(
-            weight, mask.astype(jnp.float32), ell_src, ell_freq)
+            weight, mask.astype(jnp.float32), ell_src, ell_freq, ovf=ovf)
         weight = weight + delta
         cur_in = cur_in + seen.astype(jnp.int32)
         new_ready = (cur_in == in_deg) & (~ever)
@@ -641,17 +767,19 @@ _frontier_weights_batched_ell = jax.jit(_frontier_ell_impl)
 
 
 @_device_scope("traversal")
-def _leveled_ell_impl(ell_src, ell_freq, level, num_levels):
-    """Static level schedule over the dense ELL plan: level lv's round
-    activates exactly the parents at that level, so each real edge
-    contributes once, at its parent's level (padded slots: level -1)."""
+def _leveled_ell_impl(ell_src, ell_freq, level, ovf=(), *, num_levels):
+    """Static level schedule over the ELL plan (``ovf`` as in
+    ``_frontier_ell_impl``): level lv's round activates exactly the
+    parents at that level, so each real edge contributes once, at its
+    parent's level (padded slots: level -1)."""
     from repro.kernels import ops as kops
 
     N, R = level.shape
     w = jnp.zeros((N, R), jnp.float32).at[:, 0].set(1.0)
     for lv in range(num_levels):
         active = (level == lv).astype(jnp.float32)
-        delta, _ = kops.ell_propagate_batched(w, active, ell_src, ell_freq)
+        delta, _ = kops.ell_propagate_batched(w, active, ell_src, ell_freq,
+                                              ovf=ovf)
         w = w + delta
     return w
 
@@ -661,10 +789,11 @@ _leveled_weights_batched_ell = jax.jit(_leveled_ell_impl,
 
 
 @_device_scope("traversal")
-def _frontier_fused_impl(ell_src, ell_freq, in_deg, num_levels):
+def _frontier_fused_impl(ell_src, ell_freq, in_deg, ovf=(), *, num_levels):
     """The whole frontier loop in ONE dispatch (kernels.ops runs the jitted
     fori_loop form on TPU and CPU; the fused Pallas kernel is its
-    interpret-mode oracle — kernels/ops.py DESIGN note).
+    interpret-mode oracle for the dense plan — kernels/ops.py DESIGN note;
+    ``ovf`` as in ``_frontier_ell_impl``).
 
     ``num_levels`` — the pack's max DAG depth — is the exact round count
     the while_loop form executes (level-L rules activate in round L+1), so
@@ -678,7 +807,7 @@ def _frontier_fused_impl(ell_src, ell_freq, in_deg, num_levels):
     N, R = in_deg.shape
     w0 = jnp.zeros((N, R), jnp.float32).at[:, 0].set(1.0)
     return kops.ell_frontier_fused(w0, in_deg.astype(jnp.float32),
-                                   ell_src, ell_freq, num_levels)
+                                   ell_src, ell_freq, num_levels, ovf=ovf)
 
 
 _frontier_fused_batched = jax.jit(_frontier_fused_impl,
@@ -690,7 +819,7 @@ def batched_top_down_weights(gb: GrammarBatch,
     """weights[i, r] == occurrences of corpus i's rule r. Shape [N, R_pad].
 
     Methods: ``frontier`` / ``leveled`` (COO + segment_sum),
-    ``frontier_ell`` / ``leveled_ell`` (dense ELL plan, scatter-free,
+    ``frontier_ell`` / ``leveled_ell`` (the split ELL plan, scatter-free,
     per-round), ``frontier_fused`` (the ELL frontier loop in ONE dispatch —
     kernels/propagate_fused.py), and ``auto`` (occupancy dispatch via
     ``resolve_traversal_method``: ELL when the plan is dense enough, fused
@@ -718,25 +847,28 @@ def batched_top_down_weights(gb: GrammarBatch,
         return _leveled_weights_batched(
             gb.lv_parent, gb.lv_child, gb.lv_freq, gb.lv_slices, gb.R_pad)
     if method == "frontier_ell":
-        src, freq, _, _ = gb.ell_plan()
+        src, freq, ovf, _, _ = gb.ell_split_plan()
         if gb.mesh is not None:
             return _sharded_program(_frontier_ell_impl, gb.mesh,
-                                    (3, 3, 2), 2)(src, freq, gb.in_deg)
-        return _frontier_weights_batched_ell(src, freq, gb.in_deg)
+                                    (3, 3, 2) + (3,) * len(ovf), 2)(
+                src, freq, gb.in_deg, *ovf)
+        return _frontier_weights_batched_ell(src, freq, gb.in_deg, *ovf)
     if method == "leveled_ell":
-        src, freq, level, num_levels = gb.ell_plan()
+        src, freq, ovf, level, num_levels = gb.ell_split_plan()
         if gb.mesh is not None:
             return _sharded_program(
-                _leveled_ell_impl, gb.mesh, (3, 3, 2), 2,
-                static=(("num_levels", num_levels),))(src, freq, level)
-        return _leveled_weights_batched_ell(src, freq, level, num_levels)
+                _leveled_ell_impl, gb.mesh, (3, 3, 2) + (3,) * len(ovf), 2,
+                static=(("num_levels", num_levels),))(src, freq, level, *ovf)
+        return _leveled_weights_batched_ell(src, freq, level, *ovf,
+                                            num_levels=num_levels)
     if method == "frontier_fused":
-        src, freq, _, num_levels = gb.ell_plan()
+        src, freq, ovf, _, num_levels = gb.ell_split_plan()
         if gb.mesh is not None:
             return _sharded_program(
-                _frontier_fused_impl, gb.mesh, (3, 3, 2), 2,
-                static=(("num_levels", num_levels),))(src, freq, gb.in_deg)
-        return _frontier_fused_batched(src, freq, gb.in_deg,
+                _frontier_fused_impl, gb.mesh, (3, 3, 2) + (3,) * len(ovf),
+                2, static=(("num_levels", num_levels),))(
+                src, freq, gb.in_deg, *ovf)
+        return _frontier_fused_batched(src, freq, gb.in_deg, *ovf,
                                        num_levels=num_levels)
     raise ValueError(f"unknown batched traversal method {method!r}")
 
@@ -806,9 +938,11 @@ _per_file_leveled_batched = jax.jit(_per_file_leveled_impl,
 
 @_device_scope("traversal")
 def _per_file_frontier_ell_impl(ell_src, ell_freq, in_deg, root_seen,
-                                fedge_child, fedge_file, fedge_freq, F):
-    """Per-file frontier rounds over the dense ELL plan with the VECTOR
-    payload round (kernels.ops.ell_propagate_vector).  Root-edge exclusion
+                                fedge_child, fedge_file, fedge_freq, ovf=(),
+                                *, F):
+    """Per-file frontier rounds over the ELL plan (``ovf`` as in
+    ``_frontier_ell_impl``) with the VECTOR payload round
+    (kernels.ops.ell_propagate_vector).  Root-edge exclusion
     is structural: the root has in_deg == 0 so it enters ``ever`` at init
     and its mask entry is never 1 — plan entries with src == 0 contribute
     nothing, exactly the ``ep != 0`` gate of the COO form (root edges are
@@ -824,7 +958,7 @@ def _per_file_frontier_ell_impl(ell_src, ell_freq, in_deg, root_seen,
     def body(state):
         W, cur_in, mask, ever = state
         delta, seen = kops.ell_propagate_vector(
-            W, mask.astype(jnp.float32), ell_src, ell_freq)
+            W, mask.astype(jnp.float32), ell_src, ell_freq, ovf=ovf)
         W = W + delta
         cur_in = cur_in + seen.astype(jnp.int32)
         new_ready = (cur_in == in_deg) & (~ever)
@@ -845,9 +979,11 @@ _per_file_ell_batched = jax.jit(_per_file_frontier_ell_impl,
 
 @_device_scope("traversal")
 def _per_file_leveled_ell_impl(ell_src, ell_freq, level, fedge_child,
-                               fedge_file, fedge_freq, num_levels, F):
-    """Leveled per-file traversal over the dense ELL plan: level lv's
-    vector round activates exactly the parents at that level.  The root
+                               fedge_file, fedge_freq, ovf=(), *,
+                               num_levels, F):
+    """Leveled per-file traversal over the ELL plan (``ovf`` as in
+    ``_frontier_ell_impl``): level lv's vector round activates exactly
+    the parents at that level.  The root
     (rule 0, level 0) is masked out — its edges are consumed by the
     per-file init, like the COO form's ``parent != 0`` gate."""
     from repro.kernels import ops as kops
@@ -859,7 +995,8 @@ def _per_file_leveled_ell_impl(ell_src, ell_freq, level, fedge_child,
     nonroot = (jnp.arange(R) > 0)[None, :]
     for lv in range(num_levels):
         active = ((level == lv) & nonroot).astype(jnp.float32)
-        delta, _ = kops.ell_propagate_vector(W, active, ell_src, ell_freq)
+        delta, _ = kops.ell_propagate_vector(W, active, ell_src, ell_freq,
+                                             ovf=ovf)
         W = W + delta
     return W
 
@@ -873,7 +1010,7 @@ def batched_per_file_weights(gb: GrammarBatch,
     """Wf[i, r, f] == occurrences of rule r inside file f of corpus i.
 
     The ELL methods run the vector-payload [R, F] rounds
-    (kernels/propagate_vector.py) over the same dense edge plan as the
+    (kernels/propagate_vector.py) over the same split edge plan as the
     scalar traversals — no more silent remap to the segment_sum bases
     (``resolve_batch_method`` still degrades ineligible plans, and the
     serving layer counts those downgrades).  ``frontier_fused`` runs its
@@ -906,28 +1043,26 @@ def batched_per_file_weights(gb: GrammarBatch,
             gb.lv_parent, gb.lv_child, gb.lv_freq, gb.fedge_child,
             gb.fedge_file, gb.fedge_freq, gb.lv_slices, gb.R_pad, gb.F_pad)
     if method == "frontier_ell":
-        src, freq, _, _ = gb.ell_plan()
+        src, freq, ovf, _, _ = gb.ell_split_plan()
+        args = (src, freq, gb.in_deg, gb.root_seen, gb.fedge_child,
+                gb.fedge_file, gb.fedge_freq, *ovf)
         if gb.mesh is not None:
             return _sharded_program(
                 _per_file_frontier_ell_impl, gb.mesh,
-                (3, 3, 2, 2, 2, 2, 2), 3, static=(("F", gb.F_pad),))(
-                src, freq, gb.in_deg, gb.root_seen, gb.fedge_child,
-                gb.fedge_file, gb.fedge_freq)
-        return _per_file_ell_batched(
-            src, freq, gb.in_deg, gb.root_seen, gb.fedge_child,
-            gb.fedge_file, gb.fedge_freq, gb.F_pad)
+                (3, 3, 2, 2, 2, 2, 2) + (3,) * len(ovf), 3,
+                static=(("F", gb.F_pad),))(*args)
+        return _per_file_ell_batched(*args, F=gb.F_pad)
     if method == "leveled_ell":
-        src, freq, level, num_levels = gb.ell_plan()
+        src, freq, ovf, level, num_levels = gb.ell_split_plan()
+        args = (src, freq, level, gb.fedge_child, gb.fedge_file,
+                gb.fedge_freq, *ovf)
         if gb.mesh is not None:
             return _sharded_program(
                 _per_file_leveled_ell_impl, gb.mesh,
-                (3, 3, 2, 2, 2, 2), 3,
-                static=(("num_levels", num_levels), ("F", gb.F_pad)))(
-                src, freq, level, gb.fedge_child, gb.fedge_file,
-                gb.fedge_freq)
-        return _per_file_leveled_ell_batched(
-            src, freq, level, gb.fedge_child, gb.fedge_file, gb.fedge_freq,
-            num_levels, gb.F_pad)
+                (3, 3, 2, 2, 2, 2) + (3,) * len(ovf), 3,
+                static=(("num_levels", num_levels), ("F", gb.F_pad)))(*args)
+        return _per_file_leveled_ell_batched(*args, num_levels=num_levels,
+                                             F=gb.F_pad)
     raise ValueError(f"unknown batched traversal method {method!r}")
 
 

@@ -119,32 +119,70 @@ class GrammarArrays:
         batch width).  Padding entries are src=0 / freq=0: the root has no
         in-edges and ``freq == 0`` gates padding out of every kernel.
 
-        There is no row splitting — the row index IS the destination rule,
-        so a propagation round is a pure gather + row-sum with no scatter
-        (kernels/propagate_batched.py).  The paper's 16x thread-group
-        threshold for oversized rules (§IV-B) becomes the width gate in the
-        traversal engines: grammars whose max in-degree exceeds
-        ``kernels.ops.ELL_BATCH_MAX_WIDTH`` fall back to segment_sum
-        instead of splitting rows.
+        The row index IS the destination rule, so a propagation round is a
+        pure gather + row-sum with no scatter (kernels/propagate_batched.py).
+        This dense form splits no row, so the widest row sets K for every
+        row; :meth:`in_edges_ell_split` is the form the batched engines run,
+        which splits hub rows (the paper's workload partitioning of
+        oversized rules, §IV-B).
         """
-        R = self.num_rules
-        deg = self.in_deg.astype(np.int64)
-        kmax = int(deg.max(initial=0))
+        kmax = int(self.in_deg.max(initial=0))
         if k is None:
             k = pow2_bucket(kmax)
         elif k < kmax:
             raise ValueError(f"k={k} narrower than max in-degree {kmax}")
-        src = np.zeros((R, k), np.int32)
-        freq = np.zeros((R, k), np.float32)
+        src, freq, _, _ = self.in_edges_ell_split(k, k, 0)   # no hub row
+        return src, freq
+
+    def in_edges_ell_split(self, k0: int, k1: int, base: int
+                           ) -> Tuple[np.ndarray, np.ndarray,
+                                      np.ndarray, np.ndarray]:
+        """Split per-rule in-edge plan: hub rows hand their in-edges to
+        overflow rows (core/batch.py DESIGN note).
+
+        Returns ``(src, freq)`` shaped ``[R, k0]`` and ``(ovf_src,
+        ovf_freq)`` shaped ``[O, k1]``.  A rule with at most ``k0``
+        in-edges lists them in its row, as :meth:`in_edges_ell_dense` does.
+        A hub (more than ``k0``) owns ``ceil(in_deg / k1)`` consecutive
+        overflow rows, each holding up to ``k1`` of its in-edges, and its
+        own row holds one pointer slot per overflow row: src ``base + o``,
+        freq 1.  ``base`` is the pack's padded rule count, where the
+        overflow rows' partial sums start in the round's gather source.
+        Padding is src=0 / freq=0 in both plans.
+        """
+        R = self.num_rules
+        deg = self.in_deg.astype(np.int64)
+        hub = deg > k0
+        chunks = np.where(hub, -(-deg // k1), 0)
+        if (chunks > k0).any():
+            raise ValueError(f"a hub of in-degree {int(deg.max())} needs "
+                             f"more than k0={k0} overflow rows of k1={k1}")
+        ostart = np.zeros(R + 1, np.int64)
+        np.cumsum(chunks, out=ostart[1:])
+        O = int(ostart[-1])
+        src = np.zeros((R, k0), np.int32)
+        freq = np.zeros((R, k0), np.float32)
+        osrc = np.zeros((O, k1), np.int32)
+        ofreq = np.zeros((O, k1), np.float32)
         if self.num_edges:
             order = np.argsort(self.edge_child, kind="stable")
             child = self.edge_child[order]
+            parent = self.edge_parent[order]
+            efreq = self.edge_freq[order]
             starts = np.zeros(R + 1, np.int64)
             np.cumsum(deg, out=starts[1:])
             col = np.arange(self.num_edges) - starts[child]
-            src[child, col] = self.edge_parent[order]
-            freq[child, col] = self.edge_freq[order]
-        return src, freq
+            h = hub[child]
+            src[child[~h], col[~h]] = parent[~h]
+            freq[child[~h], col[~h]] = efreq[~h]
+            orow = ostart[child[h]] + col[h] // k1
+            osrc[orow, col[h] % k1] = parent[h]
+            ofreq[orow, col[h] % k1] = efreq[h]
+        owner = np.repeat(np.arange(R), chunks)
+        slot = np.arange(O) - ostart[owner]
+        src[owner, slot] = base + np.arange(O)
+        freq[owner, slot] = 1.0
+        return src, freq, osrc, ofreq
 
     # ---------------------------------------------------- level buckets --
     def level_edge_slices(self) -> List[Tuple[int, int]]:

@@ -325,9 +325,10 @@ def _per_file_weights_ell(ga: GrammarArrays, method: str) -> jnp.ndarray:
     root_seen, fc, ff, fq, level = entry
     if method == "frontier_ell":
         return _per_file_ell_batched(srcj, freqj, in_deg, root_seen,
-                                     fc, ff, fq, ga.num_files)[0]
+                                     fc, ff, fq, F=ga.num_files)[0]
     return _per_file_leveled_ell_batched(srcj, freqj, level, fc, ff, fq,
-                                         ga.num_levels, ga.num_files)[0]
+                                         num_levels=ga.num_levels,
+                                         F=ga.num_files)[0]
 
 
 # ----------------------------------------------------------------------- #

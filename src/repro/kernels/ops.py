@@ -9,11 +9,16 @@ routing without allocating the big inputs that trigger it.
 
 DESIGN — ELL vs segment_sum dispatch: the batched traversal engine
 (core/batch.py) asks ``ell_batched_use_ref`` whether a round should run on
-the dense ``[N, R, K]`` ELL edge plan (gather form, no scatter — see
-propagate_batched.py) or stay on the COO segment_sum path.  The predicate
-is an occupancy model over (edge count, plan width K — the max in/out fan
-bucketed to a power of two, batch width N): very sparse or very wide plans
-waste K-proportional work, tiny batches never amortize a launch.
+the ELL edge plan (gather form, no scatter — see propagate_batched.py) or
+stay on the COO segment_sum path.  The predicate is an occupancy model over
+(edge count, the plan's slot count, its widest row K, batch width N): very
+sparse or very wide plans waste slot-proportional work, tiny batches never
+amortize a launch.  The batched engines run the *split* plan
+(``GrammarBatch.ell_split_plan``): hub rows hand their in-edges to overflow
+rows, so its slot count follows the in-degree distribution, not N*R*K_max,
+and the gates judge that count (``slots``).  The split round is a jnp
+program on every platform (``exec:ell_split``); the Pallas kernels below
+are the oracles of the dense plan, which the split plan equals bit for bit.
 
 DESIGN — execution path per kernel (:func:`exec_path`, a static decision
 from the platform; counted as
@@ -59,7 +64,8 @@ __all__ = [
     "ell_row_sums", "ell_propagate_batched", "ell_propagate_vector",
     "ell_frontier_fused", "bincount_use_ref", "bincount_batch_rows",
     "ell_use_ref", "ell_batched_use_ref", "ell_fused_use_kernel",
-    "ell_vector_plan_ok", "reset_backend_cache", "force_interpret",
+    "ell_vector_plan_ok", "count_ell_split", "reset_backend_cache",
+    "force_interpret",
     "exec_path", "KERNELS",
 ]
 
@@ -163,13 +169,14 @@ def ell_use_ref(num_weights: int, rows: int) -> bool:
 
 
 def ell_batched_use_ref(num_edges: int, n: int, rows: int, k: int,
-                        shards: int = 1) -> bool:
+                        shards: int = 1, slots: int | None = None) -> bool:
     """True when a batched propagation round should stay on segment_sum.
 
-    Occupancy dispatch for the dense [N, rows, K] ELL plan: reject tiny
-    batches (launch overhead), very wide plans (K beyond any realistic
-    in-degree bucket), and plans so sparse that the K-padded gather does
-    >256x the real edge work.  ``shards`` > 1 evaluates the launch-overhead
+    Occupancy dispatch for the ELL plan of ``slots`` entries (default the
+    dense ``n * rows * k``; widest row ``k``): reject tiny batches (launch
+    overhead), very wide plans (K beyond any realistic in-degree bucket),
+    and plans so sparse that the padded gather does >256x the real edge
+    work.  ``shards`` > 1 evaluates the launch-overhead
     gate per device — a corpus-sharded pack (core/batch.py DESIGN note)
     launches one program per shard over N/shards rows, so that is the width
     the launch must amortize.  Fill is a ratio and shard-invariant.
@@ -187,10 +194,29 @@ def ell_batched_use_ref(num_edges: int, n: int, rows: int, k: int,
         _count_tuned("ell_vs_seg", "miss")
         use_ref = ((n // shards) * rows < ELL_BATCH_MIN_ROWS
                    or k > ELL_BATCH_MAX_WIDTH
-                   or num_edges / max(n * rows * k, 1)
+                   or num_edges / max(_slots(n, rows, k, slots), 1)
                    < ELL_BATCH_MIN_FILL)
     _count_dispatch("ell_vs_seg", "segment_sum" if use_ref else "ell")
     return use_ref
+
+
+def _slots(n: int, rows: int, k: int, slots: int | None) -> int:
+    return n * rows * k if slots is None else int(slots)
+
+
+def count_ell_split(split: bool) -> None:
+    """Meter which ELL plan a pack built: ``split`` (hub rows overflow) or
+    ``dense`` (no row exceeds the main width)."""
+    _count_dispatch("ell_split", "split" if split else "dense")
+
+
+def _split_route(interpret) -> None:
+    """The split round has no Pallas kernel: it runs its jnp form on every
+    platform, so an explicit kernel mode cannot be honoured."""
+    if interpret is not None:
+        raise ValueError("the split ELL plan has no Pallas kernel; "
+                         "run it with interpret=None")
+    _count_dispatch("exec:ell_split", "jnp_ref")
 
 
 def ell_fused_use_kernel(rows: int) -> bool:
@@ -203,10 +229,12 @@ def ell_fused_use_kernel(rows: int) -> bool:
     return fused
 
 
-def ell_vector_plan_ok(n: int, rows: int, k: int, f: int) -> bool:
-    """True when the vector-payload [N, rows, K] x [R, F] round fits the
-    dense-plan budget (the gather materializes N*rows*K*F contributions)."""
-    ok = n * rows * k * max(f, 1) <= ELL_PLAN_MAX_ENTRIES
+def ell_vector_plan_ok(n: int, rows: int, k: int, f: int,
+                       slots: int | None = None) -> bool:
+    """True when the vector-payload round over a plan of ``slots`` entries
+    (default the dense ``n * rows * k``) fits the plan budget: the gather
+    materializes ``slots * F`` contributions."""
+    ok = _slots(n, rows, k, slots) * max(f, 1) <= ELL_PLAN_MAX_ENTRIES
     _count_dispatch("vector_plan", "ell" if ok else "segment_sum")
     return ok
 
@@ -315,13 +343,15 @@ def ell_row_sums(weights: jnp.ndarray, src: jnp.ndarray, freq: jnp.ndarray,
 
 def ell_propagate_batched(weights: jnp.ndarray, active: jnp.ndarray,
                           src: jnp.ndarray, freq: jnp.ndarray,
-                          interpret: bool | None = None):
-    """One fused propagation round over the dense [N, rows, K] ELL plan.
+                          interpret: bool | None = None, ovf: tuple = ()):
+    """One fused propagation round over the [N, rows, K] ELL plan.
 
     Returns ``(delta, seen)`` — both [N, rows] float32; see
     propagate_batched.py for the exact semantics.  Routing (interpret=None,
     module DESIGN note): the jnp form of the same plan on TPU and CPU;
     interpret=True runs the interpret-mode kernel as the validation oracle.
+    ``ovf`` — ``(ovf_src, ovf_freq)`` of a split plan — runs the split
+    round (ref.ell_propagate_split_ref) instead.
     """
     if src.ndim != 3 or freq.shape != src.shape:
         raise ValueError(f"expected matching [N, rows, K] plans, got "
@@ -330,6 +360,9 @@ def ell_propagate_batched(weights: jnp.ndarray, active: jnp.ndarray,
     if n == 0 or rows == 0 or k == 0:
         z = jnp.zeros((n, rows), jnp.float32)
         return z, z
+    if ovf:
+        _split_route(interpret)
+        return ref.ell_propagate_split_ref(weights, active, src, freq, *ovf)
     path = _dispatch("ell_batched", interpret)
     if path == "jnp_ref":
         return ref.ell_propagate_batched_ref(weights, active, src, freq)
@@ -342,14 +375,15 @@ def ell_propagate_batched(weights: jnp.ndarray, active: jnp.ndarray,
 
 def ell_propagate_vector(W: jnp.ndarray, active: jnp.ndarray,
                          src: jnp.ndarray, freq: jnp.ndarray,
-                         interpret: bool | None = None):
+                         interpret: bool | None = None, ovf: tuple = ()):
     """One vector-payload propagation round over the [N, rows, K] plan.
 
     W: [N, R, F] per-file payload; returns ``(delta [N, rows, F],
     seen [N, rows])`` — the per-file traversals' ELL round (see
     propagate_vector.py).  Routing mirrors ``ell_propagate_batched``: the
     jnp gather form in production, interpret=True (or the forced-interpret
-    lane) runs the interpret-mode kernel as the validation oracle.
+    lane) runs the interpret-mode kernel as the validation oracle.  ``ovf``
+    runs the split round, as in ``ell_propagate_batched``.
     """
     if src.ndim != 3 or freq.shape != src.shape:
         raise ValueError(f"expected matching [N, rows, K] plans, got "
@@ -360,6 +394,9 @@ def ell_propagate_vector(W: jnp.ndarray, active: jnp.ndarray,
     if n == 0 or rows == 0 or k == 0:
         return (jnp.zeros((n, rows, W.shape[-1]), jnp.float32),
                 jnp.zeros((n, rows), jnp.float32))
+    if ovf:
+        _split_route(interpret)
+        return ref.ell_propagate_split_ref(W, active, src, freq, *ovf)
     path = _dispatch("ell_vector", interpret)
     if path == "jnp_ref":
         return ref.ell_propagate_vector_ref(W, active, src, freq)
@@ -376,7 +413,7 @@ def ell_propagate_vector(W: jnp.ndarray, active: jnp.ndarray,
 def ell_frontier_fused(weights0: jnp.ndarray, in_deg: jnp.ndarray,
                        src: jnp.ndarray, freq: jnp.ndarray,
                        max_rounds: int, with_rounds: bool = False,
-                       interpret: bool | None = None):
+                       interpret: bool | None = None, ovf: tuple = ()):
     """The WHOLE frontier traversal in one dispatch (see propagate_fused.py).
 
     weights0/in_deg: [N, R]; src/freq: [N, R, K]; ``max_rounds`` must bound
@@ -386,7 +423,8 @@ def ell_frontier_fused(weights0: jnp.ndarray, in_deg: jnp.ndarray,
     residency); routing follows ``ell_propagate_batched``: production on
     TPU and CPU runs the jitted fori_loop reference (one dispatch, no
     per-round convergence test — the same structural-tax win in jnp form),
-    the interpret lanes run the Pallas kernel.
+    the interpret lanes run the Pallas kernel.  ``ovf`` runs every round
+    over the split plan (jnp form only, as in ``ell_propagate_batched``).
     """
     if src.ndim != 3 or freq.shape != src.shape:
         raise ValueError(f"expected matching [N, rows, K] plans, got "
@@ -395,6 +433,11 @@ def ell_frontier_fused(weights0: jnp.ndarray, in_deg: jnp.ndarray,
     if n == 0 or rows == 0 or k == 0:
         w = weights0.astype(jnp.float32)
         return (w, jnp.zeros(n, jnp.int32)) if with_rounds else w
+    if ovf:
+        _split_route(interpret)
+        return ref.ell_frontier_fused_ref(weights0, in_deg, src, freq,
+                                          max_rounds, with_rounds=with_rounds,
+                                          ovf=ovf)
     path = _dispatch("ell_fused", interpret)
     if path == "jnp_ref":
         return ref.ell_frontier_fused_ref(weights0, in_deg, src, freq,

@@ -75,9 +75,51 @@ def ell_propagate_vector_ref(W: jnp.ndarray, active: jnp.ndarray,
     return delta, seen
 
 
+def _plan_sums(v: jnp.ndarray, s: jnp.ndarray, src: jnp.ndarray,
+               freq: jnp.ndarray):
+    """``(sum_k freq * v[src], sum_k [freq > 0] * s[src])`` over an
+    ``[N, rows, K]`` plan; ``v`` is ``[N, R]`` or ``[N, R, F]``."""
+    n = src.shape[0]
+    flat = src.reshape(n, -1).astype(jnp.int32)
+    f = freq.astype(jnp.float32)
+    gs = jnp.take_along_axis(s, flat, axis=1).reshape(src.shape)
+    if v.ndim == 2:
+        gv = jnp.take_along_axis(v, flat, axis=1).reshape(src.shape)
+        delta = (f * gv).sum(axis=-1)
+    else:
+        gv = jnp.take_along_axis(v, flat[:, :, None], axis=1)
+        gv = gv.reshape(src.shape + (v.shape[-1],))
+        delta = (f[..., None] * gv).sum(axis=2)
+    return delta, jnp.where(f > 0, gs, 0.0).sum(axis=-1)
+
+
+def ell_propagate_split_ref(weights: jnp.ndarray, active: jnp.ndarray,
+                            src: jnp.ndarray, freq: jnp.ndarray,
+                            ovf_src: jnp.ndarray, ovf_freq: jnp.ndarray):
+    """(delta, seen) of one round over the split plan (core/batch.py
+    DESIGN note): the main plan ``[N, R, K0]`` and the overflow plan
+    ``[N, O, K1]`` that holds the in-edges of hub rows in chunks.
+
+    The overflow rows' partial sums come first, from ``weights * active``
+    and ``active``.  The main rows then gather from ``[weights * active ;
+    overflow delta]`` and ``[active ; overflow seen]``: a hub's pointer
+    slot (src ``R + o``, freq 1) adds overflow row o's partial delta and
+    its partial seen count.  Payloads are ``[N, R]`` or ``[N, R, F]``.
+    With integer-valued float32 counts below 2**24 this equals the dense
+    round bit for bit (any summation order is exact).
+    """
+    a = active.astype(jnp.float32)
+    w = weights.astype(jnp.float32)
+    wa = w * (a if w.ndim == 2 else a[..., None])
+    odelta, oseen = _plan_sums(wa, a, ovf_src, ovf_freq)
+    return _plan_sums(jnp.concatenate([wa, odelta], axis=1),
+                      jnp.concatenate([a, oseen], axis=1), src, freq)
+
+
 def ell_frontier_fused_ref(weights0: jnp.ndarray, in_deg: jnp.ndarray,
                            src: jnp.ndarray, freq: jnp.ndarray,
-                           max_rounds: int, with_rounds: bool = False):
+                           max_rounds: int, with_rounds: bool = False,
+                           ovf: tuple = ()):
     """Whole frontier loop over the ELL plan as ONE jitted fori_loop.
 
     The jnp production form of propagate_fused.py: a static ``max_rounds``
@@ -88,14 +130,18 @@ def ell_frontier_fused_ref(weights0: jnp.ndarray, in_deg: jnp.ndarray,
     float32 counts).  Returns weights [N, R] — or ``(weights, rounds)``
     with the per-corpus non-converged round count when ``with_rounds``
     (rounds costs a per-round reduction, so production leaves it off).
+    ``ovf`` — ``(ovf_src, ovf_freq)`` of a split plan — runs every round
+    as :func:`ell_propagate_split_ref`; empty, the dense round.
     """
     return _ell_frontier_fused_ref_jit(weights0, in_deg, src, freq,
-                                       int(max_rounds), bool(with_rounds))
+                                       int(max_rounds), bool(with_rounds),
+                                       tuple(ovf))
 
 
 @functools.partial(jax.jit, static_argnames=("max_rounds", "with_rounds"))
 def _ell_frontier_fused_ref_jit(weights0, in_deg, src, freq,
-                                max_rounds: int, with_rounds: bool):
+                                max_rounds: int, with_rounds: bool,
+                                ovf=()):
     n = src.shape[0]
     w0 = weights0.astype(jnp.float32)
     ind = in_deg.astype(jnp.int32)
@@ -106,7 +152,10 @@ def _ell_frontier_fused_ref_jit(weights0, in_deg, src, freq,
         w, cur, mask, ever, rounds = state
         if with_rounds:
             rounds = rounds + jnp.any(mask > 0, axis=1).astype(jnp.int32)
-        delta, seen = ell_propagate_batched_ref(w, mask, src, freq)
+        if ovf:
+            delta, seen = ell_propagate_split_ref(w, mask, src, freq, *ovf)
+        else:
+            delta, seen = ell_propagate_batched_ref(w, mask, src, freq)
         w = w + delta
         cur = cur + seen.astype(jnp.int32)
         ready = ((cur == ind) & (ever == 0.0)).astype(jnp.float32)

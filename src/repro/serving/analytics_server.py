@@ -409,7 +409,7 @@ class AnalyticsServer:
     """Groups (corpus, query) requests and runs them as batched programs."""
 
     # methods every execution path (single and batched) supports; the
-    # *_ell variants run the batched traversal on the dense ELL edge plan
+    # *_ell variants run the batched traversal on the (split) ELL edge plan
     # (core/batch.py DESIGN note), "frontier_fused" runs the whole frontier
     # loop in one kernel launch (kernels/propagate_fused.py; per-file and
     # search traversals take its per-round ELL base), and "auto" lets the

@@ -25,7 +25,10 @@ Asserts (exit code is the test result):
      sharded pack (per-shard predicate eval, aggregation, sequence-plan
      phrase matching) bit-equal to the decompress-then-scan oracle and
      the single-device batched path on the same ragged shard counts,
-     including the sharded server mode.
+     including the sharded server mode;
+  8. split ELL plan: a sharded pack whose hub rows overflow unevenly
+     across shards (one shared O_pad, so one program per shard) runs every
+     ELL method bit-equal to the oracle and the single-device pack.
 """
 
 import os
@@ -294,6 +297,37 @@ def test_sharded_ingest_appended_equals_rebuilt():
     print("sharded ingest: appended == rebuilt, post-append serving OK")
 
 
+def test_sharded_split_plan_matches_oracle():
+    from repro.data import synthetic
+
+    mesh = corpus_mesh()
+    gas = []
+    for seed, tokens, vocab, rate in ((3, 4000, 100000, 0.6),
+                                      (5, 3000, 50, 0.0),
+                                      (7, 2000, 100000, 0.6)):
+        spec = synthetic.CorpusSpec("hub", n_files=2, tokens_per_file=tokens,
+                                    vocab=vocab, phrase_rate=rate,
+                                    n_phrases=60, phrase_len=10, seed=seed)
+        g, nf = compress_files(synthetic.make_corpus(spec), vocab)
+        gas.append(flatten(g, vocab, nf))
+    gas += make_corpora(2)                 # N=5: three shards hold a hub
+    gb = shard_batch(gas, mesh)
+    assert gb.ell_split_shape()[2] > 0, gb.ell_split_shape()
+    gb1 = GrammarBatch.build(gas)
+    streams = [full_stream(ga) for ga in gas]
+    for kind in ("word_count", "term_vector", "sequence_count"):
+        wants = [oracle(ga, kind, stream=s) for ga, s in zip(gas, streams)]
+        for method in ("frontier_ell", "leveled_ell", "frontier_fused"):
+            got = run_sharded(gas, kind, mesh=mesh, method=method)
+            single = run_batched(gb1, kind, method=method)
+            for i, (g_i, w_i, s_i) in enumerate(zip(got, wants, single)):
+                assert_result_equal(g_i, w_i, kind,
+                                    f"(sharded split {method}, corpus {i})")
+                results_equal(g_i, s_i, kind,
+                              f"(split vs single-device, corpus {i})")
+    print("sharded split ELL plan == oracle == single-device OK")
+
+
 if __name__ == "__main__":
     test_sharded_matches_oracle_and_single_device()
     test_shard_signature_reuse()
@@ -302,4 +336,5 @@ if __name__ == "__main__":
     test_sharded_search_matches_oracle_and_single_device()
     test_sharded_query_operators_match_oracle_and_single_device()
     test_sharded_ingest_appended_equals_rebuilt()
+    test_sharded_split_plan_matches_oracle()
     print("SHARDED ALL OK")

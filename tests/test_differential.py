@@ -9,7 +9,7 @@ execution paths:
 
 * single-corpus (``core.analytics``, frontier + leveled traversals);
 * batched segment_sum (``run_batched`` method ``frontier`` / ``leveled``);
-* batched ELL (``frontier_ell`` / ``leveled_ell`` — the dense edge plan);
+* batched ELL (``frontier_ell`` / ``leveled_ell`` — the split edge plan);
 * device-sharded batched (``distributed.shard_batch.run_sharded``) when
   more than one device is visible — CI's multidevice lane forces 8 CPU
   host devices; tests/_shard_worker.py covers it on single-device hosts

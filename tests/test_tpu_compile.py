@@ -120,6 +120,28 @@ def test_ell_jnp_forms_compile_for_v5e(one_chip, no_persistent_cache, kind):
                  _spec(one_chip, (n, r, f), jnp.float32), vec, src, frq)
 
 
+@pytest.mark.parametrize("kind", ["split", "split_fused", "split_vector"])
+def test_split_jnp_forms_compile_for_v5e(one_chip, no_persistent_cache,
+                                         kind):
+    """The split ELL plan's programs at the widths of deployment B's pack
+    (chipbench's tadoc-B-large-files): 8 corpora of up to 4096 rules, main
+    plan K0 = 4, 256 overflow rows of K1 = 64, 4 files, 51 rounds."""
+    n, r, k0, o, k1, f = 8, 4096, 4, 256, 64, 4
+    vec = _spec(one_chip, (n, r), jnp.float32)
+    plan = (_spec(one_chip, (n, r, k0), jnp.int32),
+            _spec(one_chip, (n, r, k0), jnp.float32),
+            _spec(one_chip, (n, o, k1), jnp.int32),
+            _spec(one_chip, (n, o, k1), jnp.float32))
+    if kind == "split":
+        _compile(ref.ell_propagate_split_ref, vec, vec, *plan)
+    elif kind == "split_fused":
+        _compile(lambda w, d, s, q, os_, oq: ref.ell_frontier_fused_ref(
+            w, d, s, q, 51, ovf=(os_, oq)), vec, vec, *plan)
+    else:
+        _compile(ref.ell_propagate_split_ref,
+                 _spec(one_chip, (n, r, f), jnp.float32), vec, *plan)
+
+
 def test_tpu_routing_is_static(monkeypatch):
     """On TPU, auto routing compiles bincount and sends every gather kernel
     to its jnp form — decided from the platform, before any tracing."""
